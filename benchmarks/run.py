@@ -66,6 +66,9 @@ def main() -> None:
                     help="also write name -> us_per_call as JSON "
                          "(default path: BENCH_kernels.json)")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     benches = all_benches()
     names = args.only.split(",") if args.only else list(benches)
     print("name,us_per_call,derived")

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_arch
 from repro.core import fedavg_weights, optimize_weights, topology
 from repro.data import synthetic_tokens, partition_iid
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--rounds", type=int, default=15)
     ap.add_argument("--local-steps", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     # 1. the intermittent network (paper Fig. 2b: heterogeneous uplinks)
     link_model = topology.paper_fig2b(p_c=0.9)

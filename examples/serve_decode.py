@@ -10,6 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_arch
 from repro.models import build, count_params
 
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_arch("gemma3-1b").smoke()
     bundle = build(cfg)

@@ -32,6 +32,7 @@ import json
 
 from repro import strategies
 from repro.checkpoint import save_checkpoint
+from repro.compile_cache import enable_compile_cache
 from repro.configs import CHANNEL_PRESETS
 from repro.fl import TOPOLOGIES, ExperimentSpec, build_experiment
 
@@ -120,6 +121,7 @@ def main():
     ap.add_argument("--profile-rounds", type=int, default=4,
                     help="profiler window length in rounds")
     args = ap.parse_args()
+    enable_compile_cache()
 
     strategy_options = build_options(args.strategy_opt)
     if args.adaptive:

@@ -6,7 +6,8 @@ by the launch layer (see ``repro.launch.steps``).  Without it the
 partitioner tends to drift activations (and therefore every backward
 intermediate) to replicated layouts on the large meshes.
 
-All helpers are total no-ops when no mesh is active (smoke tests, single
+The active mesh is the one installed with ``jax.set_mesh(mesh)``.  All
+helpers are total no-ops when no mesh is active (smoke tests, single
 device) and silently drop any axis that is absent from the mesh or does
 not divide the corresponding dim, so one spec template serves every
 (arch x mesh) combination.
@@ -17,16 +18,15 @@ from __future__ import annotations
 from typing import Any, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.interpreters import pxla
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 AxisEntry = Union[None, str, Tuple[str, ...]]
 Params = Any
 
 
-def current_mesh() -> Optional[jax.sharding.Mesh]:
-    """The mesh installed by the enclosing ``with mesh:`` block, if any."""
-    mesh = pxla.thread_resources.env.physical_mesh
+def current_mesh() -> Optional[jax.sharding.AbstractMesh]:
+    """The mesh installed by the enclosing ``jax.set_mesh(mesh)``, if any."""
+    mesh = jax.sharding.get_abstract_mesh()
     return None if mesh.empty else mesh
 
 
@@ -73,7 +73,7 @@ def constrain(x: jax.Array, spec: Sequence[AxisEntry]) -> jax.Array:
     p = _fit_spec(tuple(spec), x.shape, mesh)
     if p is None:
         return x
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, p))
+    return jax.lax.with_sharding_constraint(x, p)
 
 
 def constrain_act(cfg, x: jax.Array) -> jax.Array:

@@ -1,11 +1,12 @@
 """Host-side FL training driver: samples connectivity, streams per-client
 batches, invokes the compiled round function, tracks metrics, evaluates.
 
-This is the entry point the paper-reproduction experiments and the
-examples use on CPU; the production launch path (``repro/launch``) wraps
-the same round function in pjit with mesh shardings.  Prefer building it
-declaratively through :func:`repro.fl.experiment.build_experiment` — the
-constructor below is the assembled form.
+This is the entry point the paper-reproduction experiments, the examples
+and ``chip_smoke.py`` use, on the CPU and on one TPU chip; the dry-run
+(``repro/launch/steps.py``) wraps the same round function in jit with
+mesh shardings.  Prefer building it declaratively through
+:func:`repro.fl.experiment.build_experiment` — the constructor below is
+the assembled form.
 
 Connectivity comes from a :class:`~repro.channel.ChannelProcess` — the
 paper's i.i.d. model (the default, built from ``link_model``), bursty
@@ -436,15 +437,38 @@ class FLTrainer:
             file=sys.stderr,
         )
 
-    def _run_chunks(self, r0: int, n_chunks: int, k: int,
-                    eval_every: int, verbose: bool) -> None:
-        """``n_chunks`` chunks of ``k`` rounds through the scan engine."""
+    def _chunk_fn(self):
+        """The jitted traced-scan chunk program, built on first use."""
         if self._scan_fn is None:
             self._scan_fn = jax.jit(self._make_scan_fn(
                 self._loss_fn, self._client_opt, self.server_opt, self.rc,
                 telemetry=self.telemetry),
                 donate_argnums=self._donate_round)
             self.compiles.register("scan_fn", self._scan_fn)
+        return self._scan_fn
+
+    def lower_chunk(self, k: int) -> "jax.stages.Lowered":
+        """Lower the ``k``-round chunk program that ``run(chunk=k)``
+        executes, at the trainer's current state, without running it or
+        drawing batches — e.g. to read which kernels its compiled HLO
+        calls."""
+        n, c = self.rc.n_clients, self.clients[0]
+        lead = ((k, n) if self.rc.mode == "weighted_grad"
+                else (k, n, self.rc.local_steps))
+        batches = {key: jax.ShapeDtypeStruct(
+                       (*lead, c.batch_size, *a.shape[1:]), a.dtype)
+                   for key, a in c.arrays.items()}
+        args = (self.params, self.server_state, self.agg_state, batches,
+                jax.ShapeDtypeStruct((k, n), jnp.float32),
+                jax.ShapeDtypeStruct((k, n, n), jnp.float32), self.A)
+        if self.telemetry:
+            args += (self._streak,)
+        return self._chunk_fn().lower(*args)
+
+    def _run_chunks(self, r0: int, n_chunks: int, k: int,
+                    eval_every: int, verbose: bool) -> None:
+        """``n_chunks`` chunks of ``k`` rounds through the scan engine."""
+        self._chunk_fn()
         batches = self._stack_batches(k)
         for c in range(n_chunks):
             r = r0 + c * k

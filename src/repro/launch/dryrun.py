@@ -1,5 +1,6 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
 
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh)
 combination against ShapeDtypeStruct inputs, capture memory / cost /
@@ -34,15 +35,6 @@ from repro.launch.specs import DRYRUN_LOCAL_STEPS
 from repro.launch.steps import build_step
 
 RESULTS_DIR = Path(__file__).resolve().parents[3] / "benchmarks" / "results" / "dryrun"
-
-
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict, a per-device list of dicts,
-    or None depending on jax version/backend — normalize to one dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
-    return cost or {}
 
 
 def _arg_bytes(lower_args, in_sh) -> tuple:
@@ -102,12 +94,12 @@ def _probe_costs(arch_id, shape_name, mesh, aggregation, fl_mode, cfg, k) -> dic
         arch_id, shape_name, mesh, aggregation=aggregation, fl_mode=fl_mode,
         cfg_override=_with_depth(cfg, k),
     )
-    with mesh:
+    with jax.set_mesh(mesh):
         compiled = jax.jit(
             step, in_shardings=in_sh, out_shardings=out_sh,
             donate_argnums=getattr(step, "donate_argnums", ()),
         ).lower(*lower_args).compile()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
     return {
         "flops": float(cost.get("flops", 0.0)),
@@ -131,7 +123,7 @@ def run_one(arch_id: str, shape_name: str, *, multi_pod: bool,
         arch_id, shape_name, mesh, aggregation=aggregation, fl_mode=fl_mode,
         cfg_override=cfg_override,
     )
-    with mesh:
+    with jax.set_mesh(mesh):
         # donate the carry slots the step declares (train rounds): the
         # lowering then prices params/server_state/agg_state once via
         # input-output aliasing instead of twice (alias_size_in_bytes
@@ -144,7 +136,7 @@ def run_one(arch_id: str, shape_name: str, *, multi_pod: bool,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = _cost_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
 
     coll = collective_bytes(hlo)

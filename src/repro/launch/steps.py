@@ -3,7 +3,7 @@
 ``build_step(arch_id, shape_name, mesh, ...)`` returns
 ``(step_fn, specs, in_shardings, out_shardings)`` ready for
 
-    with mesh:
+    with jax.set_mesh(mesh):
         jax.jit(step_fn, in_shardings=..., out_shardings=...).lower(**specs)
 
 Step kinds per input shape (see configs/base.py):

@@ -1,10 +1,11 @@
-"""Production FL training launcher.
+"""FL training launcher for the zoo architectures.
 
-Runs ColRel federated training of any assigned architecture on whatever
-mesh the host provides: on a TPU pod this builds the production mesh and
-pjits the round function with the sharding rules; on this CPU container
-it runs the same code single-device with the reduced (smoke) config —
-the end-to-end driver exercised in CI.
+Runs ColRel federated training of any assigned architecture in one
+process on the default device — one TPU chip, or the CPU — without a
+mesh: the round function is jitted unsharded (``jax.jit``, no
+shardings).  ``--smoke`` swaps in the architecture's reduced config.
+The client-sharded round is lowered by ``repro/launch/steps.py`` for the
+dry-run and run across chips by ``chip_smoke.py --four-chips``.
 
 Connectivity is served by a :class:`~repro.channel.ChannelProcess`
 (``--channel`` selects any preset from ``repro/configs/channels.py`` —
@@ -53,6 +54,7 @@ from repro.ckpt import (
     rng_from_json,
     rng_state_to_json,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_arch
 from repro.configs.channels import CHANNEL_PRESETS, make_channel
 from repro.core import optimize_weights, topology
@@ -85,7 +87,8 @@ def main():
     ap.add_argument("--n-clients", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
     ap.add_argument("--batch", type=int, default=4, help="per-client batch")
-    ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the architecture's reduced config, not its full one")
     ap.add_argument("--aggregation", default="colrel",
                     choices=sorted(strategy_registry.available()),
                     help="aggregation strategy (repro.strategies registry)")
@@ -137,6 +140,7 @@ def main():
                     help="disable carry-buffer donation (keeps two live "
                          "(n, d) generations; for A/B memory measurement)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # the fused kernel only exists on the colrel path; refuse the
     # silently-inert combination rather than measuring the wrong code.

@@ -74,43 +74,30 @@ class ThroughputMeter:
 class CompileTracker:
     """Detect recompiles of registered jitted functions.
 
-    ``register(name, fn)`` snapshots the function's current jit cache
+    ``register(name, fn)`` snapshots the jitted function's current cache
     size; ``check()`` returns ``{name: growth}`` for every function
     whose cache grew since the last call (one compile per distinct input
     shape is expected; growth *during steady-state training* is a
-    regression).  Functions without a ``_cache_size`` probe (non-jit
-    callables, older jax) are silently skipped.
+    regression).
     """
 
     def __init__(self):
         self._fns: Dict[str, Any] = {}
         self._seen: Dict[str, int] = {}
 
-    @staticmethod
-    def _size(fn) -> Optional[int]:
-        probe = getattr(fn, "_cache_size", None)
-        if probe is None:
-            return None
-        try:
-            return int(probe())
-        except Exception:
-            return None
-
     def register(self, name: str, fn) -> None:
-        if self._size(fn) is None:
-            return
         self._fns[name] = fn
-        self._seen[name] = self._size(fn) or 0
+        self._seen[name] = fn._cache_size()
 
     def compile_counts(self) -> Dict[str, int]:
         """Current cache size per registered function."""
-        return {n: self._size(f) or 0 for n, f in self._fns.items()}
+        return {n: f._cache_size() for n, f in self._fns.items()}
 
     def check(self) -> Dict[str, int]:
         """Cache growth per function since the previous ``check()``."""
         grew: Dict[str, int] = {}
         for name, fn in self._fns.items():
-            size = self._size(fn) or 0
+            size = fn._cache_size()
             if size > self._seen[name]:
                 grew[name] = size - self._seen[name]
             self._seen[name] = size
