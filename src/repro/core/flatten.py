@@ -30,16 +30,22 @@ implementation as the oracle/baseline (bitwise-identical values) for
 
 ``FlatSpec`` is hashable static metadata (leaf shapes + treedef), so the
 same spec can key jit caches and be rebuilt for free under tracing.
+
+Every ravel and unravel runs under the ``fl.flatten`` scope
+(:mod:`repro.telemetry.spans`), which names its compiled instructions.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.telemetry.spans import FLATTEN
 
 Params = Any
 
@@ -85,12 +91,24 @@ def flat_spec(tree: Params, *, stacked: bool = False) -> FlatSpec:
     return FlatSpec(treedef, shapes)
 
 
+def _scoped(fn):
+    """Run ``fn`` under the ``fl.flatten`` scope."""
+
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with jax.named_scope(FLATTEN):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
 def _cast(part: jax.Array, dtype) -> jax.Array:
     # per-leaf cast, fused into the segment write by XLA — never a full
     # (n, d) casted intermediate
     return part if dtype is None else part.astype(dtype)
 
 
+@_scoped
 def ravel(tree: Params, *, dtype=None) -> jax.Array:
     """Pytree -> contiguous (d,) buffer (leaf order = jax.tree.flatten).
 
@@ -112,6 +130,7 @@ def ravel(tree: Params, *, dtype=None) -> jax.Array:
     return out
 
 
+@_scoped
 def ravel_stacked(tree: Params, *, dtype=None) -> jax.Array:
     """Stacked pytree (leaves ``(n, *shape)``) -> contiguous ``(n, d)``.
 
@@ -135,6 +154,7 @@ def ravel_stacked(tree: Params, *, dtype=None) -> jax.Array:
     return out
 
 
+@_scoped
 def ravel_stacked_concat(tree: Params, *, dtype=None) -> jax.Array:
     """The pre-segmentation ``concatenate`` ravel (seed path), kept as the
     oracle/baseline: same values bit-for-bit as :func:`ravel_stacked`, but
@@ -148,6 +168,7 @@ def ravel_stacked_concat(tree: Params, *, dtype=None) -> jax.Array:
     return jnp.concatenate(parts, axis=1) if len(parts) > 1 else parts[0]
 
 
+@_scoped
 def ravel_stacked_segments(tree: Params, *, dtype=None) -> List[jax.Array]:
     """Stacked pytree -> per-leaf ``(n, d_i)`` column segments, in spec
     order.  Layout-only (reshape + per-leaf cast); the monolithic stack is
@@ -159,6 +180,7 @@ def ravel_stacked_segments(tree: Params, *, dtype=None) -> List[jax.Array]:
     return [_cast(leaf.reshape(n, -1), dtype) for leaf in leaves]
 
 
+@_scoped
 def unravel(spec: FlatSpec, flat: jax.Array, *, dtype: Optional[Any] = None) -> Params:
     """(d,) buffer -> pytree with ``spec``'s structure and leaf shapes."""
     if flat.shape != (spec.d,):
@@ -172,6 +194,7 @@ def unravel(spec: FlatSpec, flat: jax.Array, *, dtype: Optional[Any] = None) -> 
     return jax.tree.unflatten(spec.treedef, leaves)
 
 
+@_scoped
 def unravel_stacked(
     spec: FlatSpec, stack: jax.Array, *, dtype: Optional[Any] = None
 ) -> Params:

@@ -33,6 +33,12 @@ the round body in a ``lax.scan`` over a leading K-round axis, so K
 communication rounds run as one device program with a single host
 round-trip — the chunked engine ``FLTrainer.run(chunk=K)`` and the
 production launch path drive.
+
+Each part of the round runs under a ``jax.named_scope`` named in
+:mod:`repro.telemetry.spans` (``fl.local_sgd``, ``fl.aggregate``,
+``fl.server_step``, ``fl.round_metrics``; ``fl.flatten`` inside the
+aggregation, ``fl.channel_sample`` in the sampled scan).  Scopes are
+metadata: they name the compiled instructions and change no arithmetic.
 """
 
 from __future__ import annotations
@@ -50,6 +56,7 @@ from repro.dist import constrain_grads, spmd_axis_name
 from repro.optim import Optimizer
 from repro.optim.base import global_norm
 from repro.strategies.base import AggregationStrategy, ExecutionContext
+from repro.telemetry import spans as names
 
 Params = Any
 
@@ -126,10 +133,11 @@ def _local_sgd(loss_fn, client_opt: Optimizer, params: Params, batches: Params,
         return (p, ostate), loss
 
     T = jax.tree.leaves(batches)[0].shape[0]
-    (p_final, _), losses = jax.lax.scan(
-        step, (params, client_opt.init(params)), batches, unroll=T if unroll else 1
-    )
-    return _tree_sub(p_final, params), jnp.mean(losses)
+    with jax.named_scope(names.LOCAL_SGD):
+        (p_final, _), losses = jax.lax.scan(
+            step, (params, client_opt.init(params)), batches, unroll=T if unroll else 1
+        )
+        return _tree_sub(p_final, params), jnp.mean(losses)
 
 
 def make_round_fn(
@@ -183,16 +191,19 @@ def make_round_fn(
         # realized counterpart of the variance proxy S that COPT-alpha
         # (and the adaptive re-optimization schedule) minimize.  None for
         # strategies that do not collapse (their weight_sum logs as NaN).
-        w_scalar = strategy.weights(tau_up, tau_dd, A)
+        with jax.named_scope(names.AGGREGATE):
+            w_scalar = strategy.weights(tau_up, tau_dd, A)
         if rc.mode == "per_client":
             spmd = spmd_axis_name(rc.spmd_axes)
             deltas, losses = jax.vmap(
                 client_delta, in_axes=(None, 0), spmd_axis_name=spmd
             )(params, batches)
-            gdelta, agg_state = strategy.aggregate_tree(
-                deltas, tau_up, tau_dd, A, agg_state, ctx
-            )
-            mean_loss = jnp.mean(losses)
+            with jax.named_scope(names.AGGREGATE):
+                gdelta, agg_state = strategy.aggregate_tree(
+                    deltas, tau_up, tau_dd, A, agg_state, ctx
+                )
+            with jax.named_scope(names.ROUND_METRICS):
+                mean_loss = jnp.mean(losses)
 
         elif rc.mode == "client_sequential":
             w = w_scalar
@@ -201,15 +212,20 @@ def make_round_fn(
                 acc, loss_acc = carry
                 wi, client_batches = inp
                 delta, loss = client_delta(params, client_batches)
-                acc = jax.tree.map(lambda a, d: a + wi * d, acc, delta)
-                return (acc, loss_acc + loss), None
+                with jax.named_scope(names.AGGREGATE):
+                    acc = jax.tree.map(lambda a, d: a + wi * d, acc, delta)
+                with jax.named_scope(names.ROUND_METRICS):
+                    loss_acc = loss_acc + loss
+                return (acc, loss_acc), None
 
-            zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
+            with jax.named_scope(names.AGGREGATE):
+                zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
             (gdelta, loss_sum), _ = jax.lax.scan(
                 body, (zeros, 0.0), (w, batches),
                 unroll=rc.n_clients if rc.unroll else 1,
             )
-            mean_loss = loss_sum / rc.n_clients
+            with jax.named_scope(names.ROUND_METRICS):
+                mean_loss = loss_sum / rc.n_clients
 
         elif rc.mode == "weighted_grad":
             # T = 1 collapse: one backward pass over all clients' batches with
@@ -224,11 +240,14 @@ def make_round_fn(
                 losses = jax.vmap(per_client, spmd_axis_name=spmd)(batches)  # (n,)
                 return jnp.sum(w * losses), losses
 
-            (_, losses), grads = jax.value_and_grad(weighted_loss, has_aux=True)(params)
-            grads = constrain_grads(grads, grad_shardings)
-            upd, _ = client_opt.update(grads, client_opt.init(params), params)
-            gdelta = jax.tree.map(lambda u: u.astype(jnp.float32), upd)
-            mean_loss = jnp.mean(losses)
+            with jax.named_scope(names.LOCAL_SGD):
+                (_, losses), grads = jax.value_and_grad(
+                    weighted_loss, has_aux=True)(params)
+                grads = constrain_grads(grads, grad_shardings)
+                upd, _ = client_opt.update(grads, client_opt.init(params), params)
+                gdelta = jax.tree.map(lambda u: u.astype(jnp.float32), upd)
+            with jax.named_scope(names.ROUND_METRICS):
+                mean_loss = jnp.mean(losses)
 
         elif rc.mode == "weighted_flat":
             # Beyond-paper (exact) flattening of the T=1 round: instead of a
@@ -239,18 +258,20 @@ def make_round_fn(
             w = w_scalar
             n_total = jax.tree.leaves(batches)[0].shape[0]
             B_per = n_total // rc.n_clients
-            seq_w = jnp.repeat(w, B_per) / B_per
+            with jax.named_scope(names.AGGREGATE):
+                seq_w = jnp.repeat(w, B_per) / B_per
 
             def flat_loss(p):
                 return loss_fn(p, {**batches, "ce_weight": seq_w})[0]
 
-            loss_val, grads = jax.value_and_grad(flat_loss)(params)
-            # pin the gradient tree to the params' fully-sharded layout
-            # (otherwise the partitioner may materialize it replicated
-            # over the data axes — 100s of GB for the 100B+ archs)
-            grads = constrain_grads(grads, grad_shardings)
-            upd, _ = client_opt.update(grads, client_opt.init(params), params)
-            gdelta = jax.tree.map(lambda u: u.astype(jnp.float32), upd)
+            with jax.named_scope(names.LOCAL_SGD):
+                loss_val, grads = jax.value_and_grad(flat_loss)(params)
+                # pin the gradient tree to the params' fully-sharded layout
+                # (otherwise the partitioner may materialize it replicated
+                # over the data axes — 100s of GB for the 100B+ archs)
+                grads = constrain_grads(grads, grad_shardings)
+                upd, _ = client_opt.update(grads, client_opt.init(params), params)
+                gdelta = jax.tree.map(lambda u: u.astype(jnp.float32), upd)
             mean_loss = loss_val
         else:
             raise ValueError(f"unknown mode {rc.mode}")
@@ -258,29 +279,31 @@ def make_round_fn(
         # PS applies the round delta through the server optimizer by feeding
         # the negative delta as a pseudo-gradient (FedOpt convention); with
         # sgd_momentum(lr=1, beta) this is exactly the paper's PS momentum.
-        pseudo_grads = jax.tree.map(lambda d: -d, gdelta)
-        upd, server_state = server_opt.update(pseudo_grads, server_state, params)
-        new_params = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32) + u).astype(p.dtype), params, upd
-        )
-        participation = jnp.sum(tau_up.astype(jnp.float32))
-        # Wire-format-aware uplink accounting: bits put on air by the
-        # clients whose uplink delivered this round, priced at the active
-        # codec's per-coordinate wire cost (32 bits/coord for uncoded f32;
-        # the quantized strategy reports its codec descriptor).  d and the
-        # rate are static, so this folds to one multiply in the compiled
-        # round.
-        d_flat = flatten.flat_spec(params).d
-        bits_per_client = jnp.float32(
-            d_flat * strategy.wire_bits_per_coord(d_flat))
-        metrics = {
-            "loss": mean_loss,
-            "delta_norm": global_norm(gdelta),
-            "participation": participation,
-            "uplink_bits": participation * bits_per_client,
-            "weight_sum": (jnp.sum(w_scalar) if w_scalar is not None
-                           else jnp.float32(jnp.nan)),
-        }
+        with jax.named_scope(names.SERVER_STEP):
+            pseudo_grads = jax.tree.map(lambda d: -d, gdelta)
+            upd, server_state = server_opt.update(pseudo_grads, server_state, params)
+            new_params = jax.tree.map(
+                lambda p, u: (p.astype(jnp.float32) + u).astype(p.dtype), params, upd
+            )
+        with jax.named_scope(names.ROUND_METRICS):
+            participation = jnp.sum(tau_up.astype(jnp.float32))
+            # Wire-format-aware uplink accounting: bits put on air by the
+            # clients whose uplink delivered this round, priced at the
+            # active codec's per-coordinate wire cost (32 bits/coord for
+            # uncoded f32; the quantized strategy reports its codec
+            # descriptor).  d and the rate are static, so this folds to
+            # one multiply in the compiled round.
+            d_flat = flatten.flat_spec(params).d
+            bits_per_client = jnp.float32(
+                d_flat * strategy.wire_bits_per_coord(d_flat))
+            metrics = {
+                "loss": mean_loss,
+                "delta_norm": global_norm(gdelta),
+                "participation": participation,
+                "uplink_bits": participation * bits_per_client,
+                "weight_sum": (jnp.sum(w_scalar) if w_scalar is not None
+                               else jnp.float32(jnp.nan)),
+            }
         return new_params, server_state, agg_state, metrics
 
     if not telemetry:
@@ -401,8 +424,9 @@ def _scan_engine(round_fn, channel_sampler, telemetry):
                              channel_state, rng, A, streak):
             def body(carry, b):
                 p, ss, ag, cs, key, st = carry
-                key, sub = jax.random.split(key)
-                tu, td, cs = sample_fn(cs, sub)
+                with jax.named_scope(names.CHANNEL_SAMPLE):
+                    key, sub = jax.random.split(key)
+                    tu, td, cs = sample_fn(cs, sub)
                 p, ss, ag, st, metrics = round_fn(p, ss, ag, b, tu, td, A, st)
                 return (p, ss, ag, cs, key, st), metrics
 
@@ -422,8 +446,9 @@ def _scan_engine(round_fn, channel_sampler, telemetry):
                      channel_state, rng, A):
         def body(carry, b):
             p, ss, ag, cs, key = carry
-            key, sub = jax.random.split(key)
-            tu, td, cs = sample_fn(cs, sub)
+            with jax.named_scope(names.CHANNEL_SAMPLE):
+                key, sub = jax.random.split(key)
+                tu, td, cs = sample_fn(cs, sub)
             p, ss, ag, metrics = round_fn(p, ss, ag, b, tu, td, A)
             return (p, ss, ag, cs, key), metrics
 
@@ -478,13 +503,14 @@ def make_async_round_fn(
     def round_fn(params, server_state, agg_state, batches, tau_up, tau_dd, A):
         params, server_state, agg_state, metrics = base(
             params, server_state, agg_state, batches, tau_up, tau_dd, A)
-        age = agg_state["age"].astype(jnp.float32)
-        metrics = dict(
-            metrics,
-            mean_age=jnp.mean(age),
-            max_age=jnp.max(age),
-            stale_frac=jnp.mean((age > 0).astype(jnp.float32)),
-        )
+        with jax.named_scope(names.ROUND_METRICS):
+            age = agg_state["age"].astype(jnp.float32)
+            metrics = dict(
+                metrics,
+                mean_age=jnp.mean(age),
+                max_age=jnp.max(age),
+                stale_frac=jnp.mean((age > 0).astype(jnp.float32)),
+            )
         return params, server_state, agg_state, metrics
 
     if not telemetry:

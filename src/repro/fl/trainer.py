@@ -43,7 +43,11 @@ additionally compiles the instrumented round (per-client participation /
 bits-on-air vectors, a device-resident outage-streak carry, unbiasedness
 drift), and ``profile=``/``run(log_every=)`` expose the opt-in profiler
 window and throughput readout.  All of it is off by default and the
-default path's TrainLog streams are unchanged to the bit.
+default path's TrainLog streams are unchanged to the bit.  Host spans
+are always on: each block is an ``fl.block`` span whose children time
+the host loop's steps (``trainer.spans`` keeps the totals; the names are
+listed in :mod:`repro.telemetry.spans`), and :meth:`FLTrainer.op_scopes`
+names the compiled program's instructions by device scope.
 """
 
 from __future__ import annotations
@@ -74,9 +78,12 @@ from repro.telemetry import (
     CompileTracker,
     MetricsLogger,
     ProfileWindow,
+    Spans,
     ThroughputMeter,
     init_streak,
+    op_scopes,
 )
+from repro.telemetry import spans as names
 
 Params = Any
 
@@ -217,7 +224,8 @@ class FLTrainer:
         self.telemetry = bool(telemetry)
         self.metrics = metrics if metrics is not None else MetricsLogger()
         self.profile = profile
-        self.meter = ThroughputMeter()
+        self.spans = Spans()
+        self.meter = ThroughputMeter(self.spans)
         self.compiles = CompileTracker()
         self._warm_fns: set = set()
         self._streak = init_streak(n) if self.telemetry else None
@@ -307,7 +315,8 @@ class FLTrainer:
             return
         if self.round % self._ckpt_every == 0 and self.round != self._ckpt_last:
             from repro.ckpt.schema import capture_run_state
-            self._ckpt.save(self.round, capture_run_state(self))
+            with self.spans.span(names.CKPT):
+                self._ckpt.save(self.round, capture_run_state(self))
             self._ckpt_last = self.round
 
     def _finish_ckpt(self) -> None:
@@ -315,11 +324,12 @@ class FLTrainer:
         if self._ckpt is None:
             return
         try:
-            if self.round != self._ckpt_last:
-                from repro.ckpt.schema import capture_run_state
-                self._ckpt.save(self.round, capture_run_state(self))
-                self._ckpt_last = self.round
-            self._ckpt.wait()
+            with self.spans.span(names.CKPT):
+                if self.round != self._ckpt_last:
+                    from repro.ckpt.schema import capture_run_state
+                    self._ckpt.save(self.round, capture_run_state(self))
+                    self._ckpt_last = self.round
+                self._ckpt.wait()
         finally:
             self._ckpt.close()
             self._ckpt = None
@@ -352,7 +362,8 @@ class FLTrainer:
 
     def _maybe_eval(self, r: int, eval_every: int, verbose: bool) -> None:
         if eval_every and (r + 1) % eval_every == 0 and self.eval_fn is not None:
-            em = self.eval_fn(self.params)
+            with self.spans.span(names.EVAL):
+                em = self.eval_fn(self.params)
             self.metrics.log_eval(r, em)
             if verbose:
                 print(f"  round {r+1:4d}  loss={self.log.loss[-1]:.4f}  " +
@@ -361,37 +372,64 @@ class FLTrainer:
             print(f"  round {r+1:4d}  loss={self.log.loss[-1]:.4f}")
 
     # ------------------------------------------------------------------
+    def _to_device(self, *arrays):
+        """``fl.h2d``: a block's host batches and taus onto the device
+        (taus as float32), counted in ``h2d_bytes``."""
+        with self.spans.span(names.H2D):
+            batches, *taus = arrays
+            out = (jax.tree.map(jnp.asarray, batches),
+                   *(jnp.asarray(t, jnp.float32) for t in taus))
+        self.spans.count(names.H2D_BYTES,
+                         sum(x.nbytes for x in jax.tree.leaves(out)))
+        return out
+
+    def _dispatch(self, fn, args):
+        """``fl.dispatch``: call a compiled program on the carry and
+        ``args``; returns what it carries besides the model state (the
+        sampled scan's channel state and key) and its metrics."""
+        with self.spans.span(names.DISPATCH):
+            carry = (self.params, self.server_state, self.agg_state)
+            if self.telemetry:
+                *carry, self._streak, metrics = fn(*carry, *args, self._streak)
+            else:
+                *carry, metrics = fn(*carry, *args)
+            self.params, self.server_state, self.agg_state = carry[:3]
+        return carry[3:], metrics
+
+    def _end_block(self, metrics, r: int, k: int) -> None:
+        """``fl.fence`` then ``fl.log_rounds``, the last steps of a block."""
+        with self.spans.span(names.FENCE):
+            jax.block_until_ready(metrics)
+        with self.spans.span(names.LOG_ROUNDS):
+            self.metrics.log_rounds(r, metrics, k)
+
+    def _record_block(self, block, r: int, k: int) -> None:
+        """A closed ``fl.block``: to the meter and the ``timing`` event."""
+        self.meter.record(k, block.seconds)
+        if self.profile is not None:
+            self.profile.maybe_stop(r + k)
+        self.metrics.log_timing(r, k, block.seconds, block.children)
+        self._log_compile_growth(r + k - 1)
+
+    # ------------------------------------------------------------------
     def _run_one(self, r: int, eval_every: int, verbose: bool) -> None:
         """One communication round through the per-round compiled fn."""
         if self.profile is not None:
             self.profile.maybe_start(r)
-        self.meter.start()
-        tau_up, tau_dd = self.channel.tau_for_round(r)
-        batches = self._stack_batches()
-        args = (
-            self.params,
-            self.server_state,
-            self.agg_state,
-            jax.tree.map(jnp.asarray, batches),
-            jnp.asarray(tau_up, jnp.float32),
-            jnp.asarray(tau_dd, jnp.float32),
-            self.A,
-        )
-        if self.telemetry:
-            (self.params, self.server_state, self.agg_state, self._streak,
-             metrics) = self._round_fn(*args, self._streak)
-        else:
-            (self.params, self.server_state, self.agg_state,
-             metrics) = self._round_fn(*args)
-        dt = self.meter.stop(1, fence=metrics)
-        if self.profile is not None:
-            self.profile.maybe_stop(r + 1)
-        self.metrics.log_timing(r, 1, dt)
-        self._log_compile_growth(r)
-        self.metrics.log_rounds(r, metrics)
+        with self.spans.span(names.BLOCK, round=r) as block:
+            with self.spans.span(names.CHANNEL_TRACE):
+                tau_up, tau_dd = self.channel.tau_for_round(r)
+            with self.spans.span(names.STACK_BATCHES):
+                batches = self._stack_batches()
+            _, metrics = self._dispatch(
+                self._round_fn,
+                (*self._to_device(batches, tau_up, tau_dd), self.A))
+            self._end_block(metrics, r, 1)
+        self._record_block(block, r, 1)
         if self.adaptive is not None:
-            self._ingest_adaptive(r, np.asarray(tau_up), np.asarray(tau_dd),
-                                  verbose)
+            with self.spans.span(names.REOPT):
+                self._ingest_adaptive(r, np.asarray(tau_up),
+                                      np.asarray(tau_dd), verbose)
         self._maybe_eval(r, eval_every, verbose)
         self._maybe_log_throughput(r + 1)
         self.round = r + 1
@@ -452,70 +490,77 @@ class FLTrainer:
         executes, at the trainer's current state, without running it or
         drawing batches — e.g. to read which kernels its compiled HLO
         calls."""
+        return self._lower(self._chunk_fn(), (k,))
+
+    def _lower(self, fn, lead: tuple) -> "jax.stages.Lowered":
+        """Lower ``fn`` (the round or the chunk program) at the
+        trainer's state, with batches and taus of leading axes ``lead``
+        (``()`` for one round, ``(k,)`` for a chunk)."""
         n, c = self.rc.n_clients, self.clients[0]
-        lead = ((k, n) if self.rc.mode == "weighted_grad"
-                else (k, n, self.rc.local_steps))
+        steps = () if self.rc.mode == "weighted_grad" else (self.rc.local_steps,)
         batches = {key: jax.ShapeDtypeStruct(
-                       (*lead, c.batch_size, *a.shape[1:]), a.dtype)
+                       (*lead, n, *steps, c.batch_size, *a.shape[1:]), a.dtype)
                    for key, a in c.arrays.items()}
         args = (self.params, self.server_state, self.agg_state, batches,
-                jax.ShapeDtypeStruct((k, n), jnp.float32),
-                jax.ShapeDtypeStruct((k, n, n), jnp.float32), self.A)
+                jax.ShapeDtypeStruct((*lead, n), jnp.float32),
+                jax.ShapeDtypeStruct((*lead, n, n), jnp.float32), self.A)
         if self.telemetry:
             args += (self._streak,)
-        return self._chunk_fn().lower(*args)
+        return fn.lower(*args)
+
+    def op_scopes(self, k: int) -> Dict[str, str]:
+        """``{HLO instruction: device scope}`` of the program that
+        ``run(chunk=k)`` executes (the chunk program for ``k > 1``, the
+        round for ``k = 1``): each instruction of the compiled program
+        goes to the innermost ``fl.*`` scope of its metadata, or to
+        ``unscoped``.  A profiler trace names executed operations by
+        these instruction names.  Compiles the program, which is a
+        persistent-cache load where the cache holds it."""
+        lowered = (self.lower_chunk(k) if k > 1
+                   else self._lower(self._round_fn, ()))
+        return op_scopes(lowered.compile().as_text())
 
     def _run_chunks(self, r0: int, n_chunks: int, k: int,
                     eval_every: int, verbose: bool) -> None:
         """``n_chunks`` chunks of ``k`` rounds through the scan engine."""
         self._chunk_fn()
-        batches = self._stack_batches(k)
+        with self.spans.span(names.STACK_BATCHES):
+            batches = self._stack_batches(k)
         for c in range(n_chunks):
             r = r0 + c * k
             if self.profile is not None:
                 self.profile.maybe_start(r)
-            self.meter.start()
-            tau_up, tau_dd = self.channel.trace(r, k)
-            args = (
-                self.params,
-                self.server_state,
-                self.agg_state,
-                jax.tree.map(jnp.asarray, batches),
-                jnp.asarray(tau_up, jnp.float32),
-                jnp.asarray(tau_dd, jnp.float32),
-                self.A,
-            )
-            if self.telemetry:
-                (self.params, self.server_state, self.agg_state,
-                 self._streak, metrics) = self._scan_fn(*args, self._streak)
-            else:
-                (self.params, self.server_state, self.agg_state,
-                 metrics) = self._scan_fn(*args)
-            # host prefetch: the dispatch above is async, so stacking the
-            # next chunk's batches overlaps this chunk's device execution.
-            # A checkpoint taken at this boundary must see the client
-            # RNGs *before* the prefetch advances them — snapshot first.
-            from repro.ckpt.schema import rng_state_to_json
-            self._data_rng_snapshot = [rng_state_to_json(cl._rng)
-                                       for cl in self.clients]
-            batches = self._stack_batches(k) if c + 1 < n_chunks else None
-            dt = self.meter.stop(k, fence=metrics)
-            if self.profile is not None:
-                self.profile.maybe_stop(r + k)
-            self.metrics.log_timing(r, k, dt)
-            self._log_compile_growth(r + k - 1)
-            self.metrics.log_rounds(r, metrics, k)
+            with self.spans.span(names.BLOCK, round=r) as block:
+                with self.spans.span(names.CHANNEL_TRACE):
+                    tau_up, tau_dd = self.channel.trace(r, k)
+                _, metrics = self._dispatch(
+                    self._scan_fn,
+                    (*self._to_device(batches, tau_up, tau_dd), self.A))
+                # host prefetch: the dispatch above is async, so stacking
+                # the next chunk's batches overlaps this chunk's device
+                # execution.  A checkpoint taken at this boundary must see
+                # the client RNGs *before* the prefetch advances them —
+                # snapshot first.
+                from repro.ckpt.schema import rng_state_to_json
+                self._data_rng_snapshot = [rng_state_to_json(cl._rng)
+                                           for cl in self.clients]
+                if c + 1 < n_chunks:
+                    with self.spans.span(names.STACK_BATCHES):
+                        batches = self._stack_batches(k)
+                self._end_block(metrics, r, k)
+            self._record_block(block, r, k)
             if self.adaptive is not None:
                 ups, dds = np.asarray(tau_up), np.asarray(tau_dd)
-                for i in range(k):
-                    swapped = self._ingest_adaptive(r + i, ups[i], dds[i],
-                                                    verbose)
-                    if swapped and i != k - 1:  # guarded by _effective_chunk
-                        raise RuntimeError(
-                            "adaptive re-opt fired mid-chunk (round "
-                            f"{r + i}, chunk [{r}, {r + k})); the cadence "
-                            "must be a multiple of chunk"
-                        )
+                with self.spans.span(names.REOPT):
+                    for i in range(k):
+                        swapped = self._ingest_adaptive(r + i, ups[i], dds[i],
+                                                        verbose)
+                        if swapped and i != k - 1:  # guarded by _effective_chunk
+                            raise RuntimeError(
+                                "adaptive re-opt fired mid-chunk (round "
+                                f"{r + i}, chunk [{r}, {r + k})); the cadence "
+                                "must be a multiple of chunk"
+                            )
             self._maybe_eval(r + k - 1, eval_every, verbose)
             self._maybe_log_throughput(r + k)
             self.round = r + k
@@ -547,31 +592,15 @@ class FLTrainer:
             self._channel_rng = key
         if self.profile is not None:
             self.profile.maybe_start(r0)
-        self.meter.start()
-        batches = self._stack_batches(k)
-        args = (
-            self.params,
-            self.server_state,
-            self.agg_state,
-            jax.tree.map(jnp.asarray, batches),
-            self._channel_state,
-            self._channel_rng,
-            self.A,
-        )
-        if self.telemetry:
-            (self.params, self.server_state, self.agg_state,
-             self._channel_state, self._channel_rng, self._streak,
-             metrics) = self._sampled_scan_fn(*args, self._streak)
-        else:
-            (self.params, self.server_state, self.agg_state,
-             self._channel_state, self._channel_rng,
-             metrics) = self._sampled_scan_fn(*args)
-        dt = self.meter.stop(k, fence=metrics)
-        if self.profile is not None:
-            self.profile.maybe_stop(r0 + k)
-        self.metrics.log_timing(r0, k, dt)
-        self._log_compile_growth(r0 + k - 1)
-        self.metrics.log_rounds(r0, metrics, k)
+        with self.spans.span(names.BLOCK, round=r0) as block:
+            with self.spans.span(names.STACK_BATCHES):
+                batches = self._stack_batches(k)
+            (batches,) = self._to_device(batches)
+            (self._channel_state, self._channel_rng), metrics = self._dispatch(
+                self._sampled_scan_fn,
+                (batches, self._channel_state, self._channel_rng, self.A))
+            self._end_block(metrics, r0, k)
+        self._record_block(block, r0, k)
         self._maybe_eval(r0 + k - 1, eval_every, verbose)
         self._maybe_log_throughput(r0 + k)
         self.round = r0 + k

@@ -94,4 +94,5 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_pallas",
     )(q, k, v)

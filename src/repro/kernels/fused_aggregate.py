@@ -88,6 +88,7 @@ def fused_aggregate_pallas(
         out_specs=pl.BlockSpec((1, bd), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="fused_aggregate_pallas",
     )(a, tdt, tu, updates)
     return out.reshape(d)
 
@@ -129,5 +130,6 @@ def row_stream_pallas(
         out_specs=pl.BlockSpec((1, bd), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="row_stream_pallas",
     )(wr, segment)
     return out.reshape(d)

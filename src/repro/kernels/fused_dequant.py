@@ -100,6 +100,7 @@ def fused_dequant_aggregate_pallas(
         out_specs=pl.BlockSpec((1, bd), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="fused_dequant_aggregate_pallas",
     )(a, tdt, tu, s, q)
     return out.reshape(d)
 

@@ -97,6 +97,7 @@ def fused_memory_update_pallas(
             jax.ShapeDtypeStruct((n, d), jnp.float32),
         ),
         interpret=interpret,
+        name="fused_memory_update_pallas",
     )(a, tdt, tcol, updates, buffer)
     return delta.reshape(d), contrib
 
@@ -149,5 +150,6 @@ def memory_stream_pallas(
             jax.ShapeDtypeStruct((n, d), jnp.float32),
         ),
         interpret=interpret,
+        name="memory_stream_pallas",
     )(mix.astype(jnp.float32), tcol, segment, buf_seg)
     return delta.reshape(d), contrib

@@ -79,6 +79,7 @@ def block_relay_mix_pallas(
         out_specs=pl.BlockSpec((m, bd), lambda i, c: (c, i)),
         out_shape=jax.ShapeDtypeStruct((n, d), updates.dtype),
         interpret=interpret,
+        name="block_relay_mix_pallas",
     )(a, tbt, updates)
 
 
@@ -146,6 +147,7 @@ def block_fused_aggregate_pallas(
         out_specs=pl.BlockSpec((1, bd), lambda i, c: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="block_fused_aggregate_pallas",
     )(a, tbt, tu, updates)
     return out.reshape(d)
 
@@ -197,5 +199,6 @@ def block_row_stream_pallas(
         out_specs=pl.BlockSpec((1, bd), lambda i, c: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, d), jnp.float32),
         interpret=interpret,
+        name="block_row_stream_pallas",
     )(wr, segment)
     return out.reshape(d)

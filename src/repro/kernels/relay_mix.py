@@ -61,4 +61,5 @@ def relay_mix_pallas(
         out_specs=pl.BlockSpec((n, bd), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((n, d), updates.dtype),
         interpret=interpret,
+        name="relay_mix_pallas",
     )(m, updates)

@@ -98,4 +98,5 @@ def ssd_scan_pallas(
         out_shape=jax.ShapeDtypeStruct((BH, T, Dv), q.dtype),
         scratch_shapes=[pltpu.VMEM((Dk, Dv), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan_pallas",
     )(q, k, v, ld)

@@ -12,9 +12,12 @@
    ``health.recompile``), and a :class:`RunManifest` written at run
    start (config digest, strategy/channel/codec, mesh, backend, git
    SHA).
-3. **Timing tier** (:mod:`repro.telemetry.timing`) — fenced wall-clock
-   throughput, jit recompile tracking, and opt-in
-   ``jax.profiler.trace`` capture windows.
+3. **Timing tier** (:mod:`repro.telemetry.timing`,
+   :mod:`repro.telemetry.spans`) — the names of the program's own work
+   (device scopes on the compiled round, host spans on the trainer's
+   loop, each listed once in ``spans``), fenced wall-clock throughput,
+   jit recompile tracking, and opt-in ``jax.profiler.trace`` capture
+   windows.
 
 Everything is stdlib + numpy + jax; nothing here imports the FL stack
 (the trainer imports *us*), and with no sinks attached the whole layer
@@ -36,7 +39,13 @@ from repro.telemetry.logger import (
     MetricsSink,
 )
 from repro.telemetry.manifest import RunManifest, config_digest, git_sha
-from repro.telemetry.timing import CompileTracker, ProfileWindow, ThroughputMeter
+from repro.telemetry.spans import Spans, op_scopes
+from repro.telemetry.timing import (
+    CompileTracker,
+    ProfileWindow,
+    ThroughputMeter,
+    profiler_options,
+)
 
 __all__ = [
     "VECTOR_METRICS",
@@ -55,4 +64,7 @@ __all__ = [
     "CompileTracker",
     "ProfileWindow",
     "ThroughputMeter",
+    "profiler_options",
+    "Spans",
+    "op_scopes",
 ]

@@ -35,6 +35,8 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.spans import TELEMETRY
+
 __all__ = [
     "VECTOR_METRICS",
     "init_streak",
@@ -92,16 +94,17 @@ def instrument_round_fn(round_fn, wire_bits_per_coord):
                 tau_up, tau_dd, A, streak):
         params, server_state, agg_state, metrics = round_fn(
             params, server_state, agg_state, batches, tau_up, tau_dd, A)
-        streak = update_streak(streak, tau_up)
-        d_flat = flatten.flat_spec(params).d
-        bits = jnp.float32(d_flat * wire_bits_per_coord(d_flat))
-        metrics = dict(
-            metrics,
-            client_participation=tau_up.astype(jnp.float32),
-            client_uplink_bits=tau_up.astype(jnp.float32) * bits,
-            outage_streak=streak,
-            weight_drift=jnp.abs(metrics["weight_sum"] - 1.0),
-        )
+        with jax.named_scope(TELEMETRY):
+            streak = update_streak(streak, tau_up)
+            d_flat = flatten.flat_spec(params).d
+            bits = jnp.float32(d_flat * wire_bits_per_coord(d_flat))
+            metrics = dict(
+                metrics,
+                client_participation=tau_up.astype(jnp.float32),
+                client_uplink_bits=tau_up.astype(jnp.float32) * bits,
+                outage_streak=streak,
+                weight_drift=jnp.abs(metrics["weight_sum"] - 1.0),
+            )
         return params, server_state, agg_state, streak, metrics
 
     return wrapped

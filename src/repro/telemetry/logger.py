@@ -17,7 +17,8 @@ Events flow to pluggable sinks:
 * :class:`MemorySink` — in-process list (tests, report tooling).
 
 Event kinds: ``round`` (per-round scalars), ``eval``, ``reopt``,
-``timing`` (per-chunk wall clock + rounds/sec), ``health.nan`` (a
+``timing`` (per-chunk wall clock + rounds/sec, and the seconds of the
+chunk's host spans), ``health.nan`` (a
 non-finite loss — emitted as a structured event instead of being
 silently appended), ``health.recompile`` (jit cache growth), and
 ``summary.clients`` (end-of-run per-client aggregates of the
@@ -287,9 +288,14 @@ class MetricsLogger:
         self.log.S_true.append(S_true)
         self.emit("reopt", round=r, S_est=S_est, S_true=S_true, p_err=p_err)
 
-    def log_timing(self, r0: int, rounds: int, seconds: float) -> None:
+    def log_timing(self, r0: int, rounds: int, seconds: float,
+                   spans: Optional[Dict[str, float]] = None) -> None:
+        """One block's wall clock; ``spans`` are the seconds of the
+        block's host steps by span name (its ``fl.block`` children)."""
+        extra = {} if spans is None else {"spans": dict(spans)}
         self.emit("timing", round0=r0, rounds=rounds, seconds=seconds,
-                  rounds_per_sec=rounds / seconds if seconds > 0 else 0.0)
+                  rounds_per_sec=rounds / seconds if seconds > 0 else 0.0,
+                  **extra)
 
     def log_recompiles(self, grew: Dict[str, int], r: int) -> None:
         for name, growth in grew.items():

@@ -1,12 +1,14 @@
 """Timing, throughput and profiling instrumentation.
 
-Three host-side probes, all opt-in and all safe to leave attached:
+Three host-side probes, all safe to leave attached:
 
-* :class:`ThroughputMeter` — per-chunk wall-clock with explicit
-  ``jax.block_until_ready`` fencing (async dispatch otherwise makes
+* :class:`ThroughputMeter` — rounds/sec per block and cumulatively, from
+  the seconds of each block's ``fl.block`` host span
+  (:mod:`repro.telemetry.spans`), which ends with a
+  ``jax.block_until_ready`` fence (async dispatch otherwise makes
   ``perf_counter`` deltas measure the *enqueue*, not the execution).
-  Tracks rounds/sec per chunk and cumulatively; the ROADMAP's async
-  direction measures convergence against wall-clock, which starts here.
+  The ROADMAP's async direction measures convergence against
+  wall-clock, which starts here.
 * :class:`CompileTracker` — snapshots the jit cache sizes of registered
   compiled functions and reports growth, catching recompile regressions
   (a shape-unstable carry silently retracing every chunk turns a 20x
@@ -19,51 +21,61 @@ Three host-side probes, all opt-in and all safe to leave attached:
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional
 
 import jax
 
-__all__ = ["ThroughputMeter", "CompileTracker", "ProfileWindow"]
+from repro.telemetry.spans import BLOCK, FENCE, Span, Spans
+
+__all__ = ["ThroughputMeter", "CompileTracker", "ProfileWindow",
+           "profiler_options"]
 
 
 class ThroughputMeter:
     """Wall-clock rounds/sec with device fencing.
 
-    Usage per execution block (one round or one K-round chunk)::
+    The trainer times each execution block (one round or one K-round
+    chunk) as an ``fl.block`` span of its :class:`Spans` and hands the
+    span's seconds to :meth:`record`.  ``start``/``stop`` do the same
+    for a caller with no span of its own::
 
         meter.start()
         ... dispatch ... (+ host prefetch work)
         dt = meter.stop(rounds=k, fence=metrics)
 
-    ``fence`` is block_until_ready'd before the clock stops, so the
-    interval covers the device execution, not just its enqueue.  Fencing
-    on the metrics the caller is about to read anyway adds no extra
-    sync.
+    ``fence`` is block_until_ready'd (an ``fl.fence`` span) before the
+    block closes, so the interval covers the device execution, not just
+    its enqueue.  Fencing on the metrics the caller is about to read
+    anyway adds no extra sync.
     """
 
-    def __init__(self):
-        self._t0: Optional[float] = None
+    def __init__(self, spans: Optional[Spans] = None):
+        self.spans = spans if spans is not None else Spans()
+        self._block: Optional[Span] = None
         self.chunks: List[Dict[str, float]] = []
         self.total_rounds = 0
         self.total_seconds = 0.0
 
     def start(self) -> None:
-        self._t0 = time.perf_counter()
+        self._block = self.spans.open(BLOCK)
 
     def stop(self, rounds: int, fence: Any = None) -> float:
-        """Fence, stop the clock, record; returns the elapsed seconds."""
-        if self._t0 is None:
+        """Fence, close the block, record; returns the elapsed seconds."""
+        if self._block is None:
             raise RuntimeError("ThroughputMeter.stop() without start()")
         if fence is not None:
-            jax.block_until_ready(fence)
-        dt = time.perf_counter() - self._t0
-        self._t0 = None
-        self.chunks.append({"rounds": rounds, "seconds": dt,
-                            "rounds_per_sec": rounds / dt if dt > 0 else 0.0})
+            with self.spans.span(FENCE):
+                jax.block_until_ready(fence)
+        block, self._block = self._block, None
+        return self.record(rounds, self.spans.close(block).seconds)
+
+    def record(self, rounds: int, seconds: float) -> float:
+        """Record one block of ``rounds`` rounds that took ``seconds``."""
+        self.chunks.append({"rounds": rounds, "seconds": seconds,
+                            "rounds_per_sec": rounds / seconds if seconds > 0 else 0.0})
         self.total_rounds += rounds
-        self.total_seconds += dt
-        return dt
+        self.total_seconds += seconds
+        return seconds
 
     def rounds_per_sec(self) -> float:
         """Cumulative throughput over every recorded block."""
@@ -104,6 +116,15 @@ class CompileTracker:
         return grew
 
 
+def profiler_options() -> "jax.profiler.ProfileOptions":
+    """Host events of the first level only: at the default level the
+    runtime's per-chunk transpose events cost seconds per block of the
+    paper's job."""
+    options = jax.profiler.ProfileOptions()
+    options.host_tracer_level = 1
+    return options
+
+
 class ProfileWindow:
     """An opt-in ``jax.profiler.trace`` capture over rounds
     ``[start, start + rounds)``.
@@ -113,6 +134,7 @@ class ProfileWindow:
     the block that ends before round ``r_next``; the window opens/closes
     on the enclosing block boundaries (a chunked run profiles whole
     chunks).  ``close()`` force-stops a window left open at run end.
+    The trace starts with :func:`profiler_options`.
     """
 
     def __init__(self, profile_dir: str, start: int = 0, rounds: int = 1):
@@ -130,7 +152,8 @@ class ProfileWindow:
         if self.active:
             return True
         if not self.done and r >= self.start:
-            jax.profiler.start_trace(self.profile_dir)
+            jax.profiler.start_trace(self.profile_dir,
+                                     profiler_options=profiler_options())
             self.active = True
         return self.active
 

@@ -358,13 +358,19 @@ def test_compile_tracker_detects_retrace():
 
 def test_profile_window_state_machine(monkeypatch):
     calls = []
-    monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+    levels = []
+
+    def start_trace(d, profiler_options=None):
+        levels.append(profiler_options.host_tracer_level)
+        calls.append(("start", d))
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop", None)))
     w = ProfileWindow("/tmp/prof", start=4, rounds=4)
     assert not w.maybe_start(0) and calls == []
     assert w.maybe_start(4) and calls == [("start", "/tmp/prof")]
+    assert levels == [1]  # host events of the first level only
     assert w.maybe_start(6)            # still capturing, no double-start
     assert not w.maybe_stop(6)         # window not yet past r=8
     assert w.maybe_stop(8) and calls[-1] == ("stop", None)

@@ -96,6 +96,27 @@ def test_kernel_compiles_for_v5e(name, one_chip, on_tpu):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("name,kernel", [("fused_aggregate_f32", "fused_aggregate_pallas"),
+                                         ("row_stream_f32", "row_stream_pallas")])
+def test_kernel_instruction_keeps_its_name_for_v5e(name, kernel, one_chip, on_tpu):
+    """The benchmark finds the aggregation kernel in a trace by the HLO
+    instruction's name: the pallas_call's ``name=`` must keep it, under
+    the round's ``fl.aggregate`` scope."""
+    from repro.telemetry import op_scopes
+
+    fn, args = KERNEL_CASES[name]
+
+    def scoped(*a):
+        with jax.named_scope("fl.aggregate"):
+            return fn(*a)
+
+    text = jax.jit(scoped).lower(
+        *(_spec(one_chip, s, dt) for s, dt in args)).compile().as_text()
+    found = {op: scope for op, scope in op_scopes(text).items()
+             if op.split(".")[0] == kernel}
+    assert found and set(found.values()) == {"fl.aggregate"}
+
+
 def test_per_client_kernel_round_compiles_for_v5e(one_chip, on_tpu):
     from repro.configs import colrel_paper
     from repro.core.flatten import flat_spec
