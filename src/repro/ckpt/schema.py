@@ -22,9 +22,10 @@ key                     contents
                         is off)
 ``clients``             per-client data-RNG generator states (JSON-encoded
                         ``bit_generator.state``) at the *consumed-round
-                        boundary* — the chunked engine prefetches the next
-                        chunk's batches before the checkpoint point, so the
-                        trainer snapshots these before prefetching
+                        boundary* — the block loop prefetches the next
+                        round's or chunk's batches before the checkpoint
+                        point, so the trainer snapshots these before
+                        prefetching
 ``channel``             the channel process's generator/chain state, via its
                         ``checkpoint_state``/``restore_state`` (restores
                         regenerate the current block bitwise)

@@ -26,14 +26,18 @@ lives on the trainer and threads through the compiled round.
 the multi-round scan engine — K rounds compiled into one device program
 (:func:`~repro.fl.round.make_scan_round_fn`), connectivity served as a
 bulk ``channel.trace`` per chunk, batches pre-stacked in one vectorized
-gather with the next chunk prepared while the device executes the
-current one, and per-round metrics bulk-appended from the stacked
-``(K,)`` outputs.  The trajectory is bitwise-identical to the per-round
-loop: both consume the same channel/batch streams and the scan body *is*
-the loop's round function.  Adaptive re-optimization and eval stay
-correct by construction — the chunk size must divide their cadences (and
-re-opts then land exactly on chunk boundaries); otherwise the trainer
-falls back to the per-round loop.
+gather, and per-round metrics bulk-appended from the stacked ``(K,)``
+outputs.  The per-round and the chunk loop are one block loop (a block
+is a round or a chunk): between a block's dispatch and its fence the
+host stacks the next block's batches, while the device runs, unless the
+block is the last of its ``run`` call or the next round starts an
+aligned chunk (which stacks its own).  The trajectory is
+bitwise-identical to the per-round loop: both consume the same
+channel/batch streams and the scan body *is* the loop's round function.
+Adaptive re-optimization and eval stay correct by construction — the
+chunk size must divide their cadences (and re-opts then land exactly on
+chunk boundaries); otherwise the trainer falls back to the per-round
+loop.
 
 **Telemetry** (DESIGN.md §11): every metric stream — both execution
 paths — routes through one :class:`~repro.telemetry.MetricsLogger`
@@ -255,7 +259,7 @@ class FLTrainer:
         self._channel_rng = None
         # checkpoint/resume (DESIGN.md §12): the authoritative round
         # counter, the client-RNG snapshot at the consumed-round boundary
-        # (the chunked engine prefetches past it), and the per-run async
+        # (the block loop prefetches past it), and the per-run async
         # checkpointer wiring set up by `run`.
         self.round = 0
         self._data_rng_snapshot: Optional[List[str]] = None
@@ -279,17 +283,20 @@ class FLTrainer:
         return out
 
     # -- checkpoint/resume (DESIGN.md §12) -----------------------------
+    def _live_rng_states(self) -> List[str]:
+        from repro.ckpt.schema import rng_state_to_json
+        return [rng_state_to_json(c._rng) for c in self.clients]
+
     def _client_rng_states(self) -> List[str]:
         """Per-client data-RNG states at the consumed-round boundary.
 
-        The chunked engine prefetches the next chunk's batches *before*
-        the checkpoint point, so the live generators sit one chunk ahead
-        of the boundary; ``_run_chunks`` snapshots the boundary states
-        pre-prefetch and this prefers that snapshot."""
+        The block loop prefetches the next block's batches *before* the
+        checkpoint point, so the live generators then sit one block
+        ahead of the boundary; ``_run_blocks`` snapshots the boundary
+        states pre-prefetch and this prefers that snapshot."""
         if self._data_rng_snapshot is not None:
             return list(self._data_rng_snapshot)
-        from repro.ckpt.schema import rng_state_to_json
-        return [rng_state_to_json(c._rng) for c in self.clients]
+        return self._live_rng_states()
 
     def save_checkpoint(self, path) -> pathlib.Path:
         """Synchronously write the complete run state to one file."""
@@ -412,31 +419,6 @@ class FLTrainer:
         self._log_compile_growth(r + k - 1)
 
     # ------------------------------------------------------------------
-    def _run_one(self, r: int, eval_every: int, verbose: bool) -> None:
-        """One communication round through the per-round compiled fn."""
-        if self.profile is not None:
-            self.profile.maybe_start(r)
-        with self.spans.span(names.BLOCK, round=r) as block:
-            with self.spans.span(names.CHANNEL_TRACE):
-                tau_up, tau_dd = self.channel.tau_for_round(r)
-            with self.spans.span(names.STACK_BATCHES):
-                batches = self._stack_batches()
-            _, metrics = self._dispatch(
-                self._round_fn,
-                (*self._to_device(batches, tau_up, tau_dd), self.A))
-            self._end_block(metrics, r, 1)
-        self._record_block(block, r, 1)
-        if self.adaptive is not None:
-            with self.spans.span(names.REOPT):
-                self._ingest_adaptive(r, np.asarray(tau_up),
-                                      np.asarray(tau_dd), verbose)
-        self._maybe_eval(r, eval_every, verbose)
-        self._maybe_log_throughput(r + 1)
-        self.round = r + 1
-        self._data_rng_snapshot = None  # live RNGs sit at the boundary
-        self._maybe_ckpt()
-
-    # ------------------------------------------------------------------
     def _effective_chunk(self, chunk: int, eval_every: int) -> int:
         """Largest usable chunk: the requested one when it divides every
         host-side cadence (adaptive re-opt, eval) — so those events land
@@ -520,37 +502,48 @@ class FLTrainer:
                    else self._lower(self._round_fn, ()))
         return op_scopes(lowered.compile().as_text())
 
-    def _run_chunks(self, r0: int, n_chunks: int, k: int,
+    def _run_blocks(self, r0: int, n_blocks: int, k: int,
                     eval_every: int, verbose: bool) -> None:
-        """``n_chunks`` chunks of ``k`` rounds through the scan engine."""
-        self._chunk_fn()
+        """``n_blocks`` blocks of ``k`` rounds from round ``r0``: single
+        rounds through the round program (``k = 1``) or chunks through
+        the scan engine.  Each block but the last stacks the next
+        block's batches between its dispatch and its fence, so the
+        host's gather overlaps the device's work; the batches stay host
+        arrays until their own block's ``fl.h2d``."""
+        if k == 1:
+            fn, trace = self._round_fn, self.channel.tau_for_round
+        else:
+            fn, trace = self._chunk_fn(), lambda r: self.channel.trace(r, k)
         with self.spans.span(names.STACK_BATCHES):
             batches = self._stack_batches(k)
-        for c in range(n_chunks):
+        for c in range(n_blocks):
             r = r0 + c * k
             if self.profile is not None:
                 self.profile.maybe_start(r)
             with self.spans.span(names.BLOCK, round=r) as block:
                 with self.spans.span(names.CHANNEL_TRACE):
-                    tau_up, tau_dd = self.channel.trace(r, k)
+                    tau_up, tau_dd = trace(r)
                 _, metrics = self._dispatch(
-                    self._scan_fn,
-                    (*self._to_device(batches, tau_up, tau_dd), self.A))
+                    fn, (*self._to_device(batches, tau_up, tau_dd), self.A))
                 # host prefetch: the dispatch above is async, so stacking
-                # the next chunk's batches overlaps this chunk's device
+                # the next block's batches overlaps this block's device
                 # execution.  A checkpoint taken at this boundary must see
                 # the client RNGs *before* the prefetch advances them —
                 # snapshot first.
-                from repro.ckpt.schema import rng_state_to_json
-                self._data_rng_snapshot = [rng_state_to_json(cl._rng)
-                                           for cl in self.clients]
-                if c + 1 < n_chunks:
+                if c + 1 < n_blocks:
+                    self._data_rng_snapshot = self._live_rng_states()
                     with self.spans.span(names.STACK_BATCHES):
                         batches = self._stack_batches(k)
+                    self.spans.count(names.PREFETCHED_ROUNDS, k)
+                else:
+                    self._data_rng_snapshot = None  # live RNGs sit at the boundary
                 self._end_block(metrics, r, k)
+                del metrics  # logged: free its device buffers before the next block
             self._record_block(block, r, k)
             if self.adaptive is not None:
                 ups, dds = np.asarray(tau_up), np.asarray(tau_dd)
+                if k == 1:  # one round's taus have no leading round axis
+                    ups, dds = ups[None], dds[None]
                 with self.spans.span(names.REOPT):
                     for i in range(k):
                         swapped = self._ingest_adaptive(r + i, ups[i], dds[i],
@@ -694,11 +687,15 @@ class FLTrainer:
         while r < end:
             if k > 1 and r % k == 0 and r + k <= end:
                 n_chunks = (end - r) // k
-                self._run_chunks(r, n_chunks, k, eval_every, verbose)
+                self._run_blocks(r, n_chunks, k, eval_every, verbose)
                 r += n_chunks * k
             else:
-                self._run_one(r, eval_every, verbose)
-                r += 1
+                # single rounds up to the next aligned full chunk (which
+                # stacks its own batches), else to the end of the call
+                aligned = r + (-r) % k
+                stop = aligned if k > 1 and aligned + k <= end else end
+                self._run_blocks(r, stop - r, 1, eval_every, verbose)
+                r = stop
         return self._finish_run()
 
     def _finish_run(self) -> TrainLog:

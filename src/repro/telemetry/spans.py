@@ -59,7 +59,8 @@ SPANS = (BLOCK, CHANNEL_TRACE, STACK_BATCHES, H2D, DISPATCH, FENCE,
 
 # -- counters (``Spans.count``) -----------------------------------------------
 H2D_BYTES = "h2d_bytes"               # bytes put on the device at fl.h2d
-COUNTERS = (H2D_BYTES,)
+PREFETCHED_ROUNDS = "prefetched_rounds"  # rounds stacked while the block before ran
+COUNTERS = (H2D_BYTES, PREFETCHED_ROUNDS)
 
 
 class Span:

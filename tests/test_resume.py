@@ -41,13 +41,16 @@ N, D = 6, 12
 
 
 def _make_trainer(strategy="colrel", *, telemetry=False, adaptive=None,
-                  metrics=None, seed=3):
+                  metrics=None, seed=3, row_noise=0.0):
     """A tiny least-squares problem over a bursty channel with a small
     block size (4), so a 6-round run crosses a buffer refill and resume
-    exercises both mid-block and cross-block regeneration."""
+    exercises both mid-block and cross-block regeneration.  With
+    ``row_noise`` a client's rows differ, so its batches depend on its
+    data RNG's state."""
     rng = np.random.default_rng(0)
     targets = rng.normal(size=(N, D)).astype(np.float32)
-    clients = [ClientDataset({"t": np.repeat(targets[i][None], 64, 0)},
+    noise = row_noise * rng.normal(size=(N, 64, D)).astype(np.float32)
+    clients = [ClientDataset({"t": np.repeat(targets[i][None], 64, 0) + noise[i]},
                              batch_size=4, seed=i) for i in range(N)]
     if strategy == "clustered":
         model = topology.clustered_blocks(N, 0.5, 3, p_intra=0.8, rho=0.6)
@@ -124,6 +127,21 @@ def test_misaligned_cadence_is_an_error(tmp_path):
     t = _make_trainer()
     with pytest.raises(ValueError, match="multiple of"):
         t.run(6, chunk=3, ckpt_dir=tmp_path, ckpt_every=2)
+
+
+def test_per_round_prefetch_resumes_bitwise(tmp_path):
+    """The per-round loop stacks round r+1's batches before round r's
+    checkpoint; the checkpoint holds the client RNGs from before that
+    prefetch, so resuming from round 6 continues bitwise."""
+    ref = _make_trainer(row_noise=0.5)
+    ref.run(9)
+    a = _make_trainer(row_noise=0.5)
+    a.run(9, ckpt_dir=tmp_path, ckpt_every=3)
+    assert CheckpointWriter(tmp_path).steps() == [3, 6, 9]
+    _assert_same_run(ref, a)
+    b = _make_trainer(row_noise=0.5)
+    b.run(9, resume_from=CheckpointWriter(tmp_path).path_for(6))
+    _assert_same_run(ref, b)
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,9 @@ Five layers:
      the per-round loop bitwise (loss/participation/weight-sum/uplink-
      bits trajectories and final params), including resumed runs, tail
      remainders, and adaptive re-optimization at chunk boundaries (with
-     the misaligned-cadence fallback);
+     the misaligned-cadence fallback); a per-round call, which stacks
+     each round's batches while the round before runs, matches
+     single-round calls;
   4. the in-scan channel samplers — marginals match the process law and
      the sampled-tau scan variant runs end to end;
   5. the wire-format-aware uplink accounting and the production
@@ -303,6 +305,31 @@ def test_trainer_chunked_matches_loop_markov_and_resume():
     t2.run(7)           # per-round prefix ...
     t2.run(13, chunk=5)  # ... resumed chunked: aligns at round 10
     _assert_logs_bitwise(t1, t2)
+
+
+@pytest.mark.parametrize("adaptive", [False, True], ids=["markov", "adaptive"])
+def test_per_round_call_prefetch_matches_single_round_calls(adaptive):
+    """One ``run(R)`` call stacks each round's batches while the round
+    before runs; R ``run(1)`` calls never prefetch.  Same trajectory to
+    the bit, re-opts included."""
+    R = 12
+    mk = lambda: _quadratic_trainer(
+        channel=MarkovChannel(gilbert_elliott(topology.paper_fig2a(),
+                                              memory=0.8), seed=1, block=16),
+        adaptive=AdaptiveWeightSchedule(10, AdaptiveConfig(
+            every=5, warmup=3, sweeps=3, fine_tune_sweeps=3)) if adaptive else None,
+        A=fedavg_weights(10) if adaptive else None, local_steps=2)
+    t1 = mk()
+    t1.run(R)
+    t2 = mk()
+    for _ in range(R):
+        t2.run(1)
+    _assert_logs_bitwise(t1, t2)
+    assert t1.spans.counters["prefetched_rounds"] == R - 1
+    assert t2.spans.counters.get("prefetched_rounds", 0) == 0
+    assert t1.log.reopt_rounds == t2.log.reopt_rounds == ([4, 9] if adaptive else [])
+    assert t1.log.S_est == t2.log.S_est
+    np.testing.assert_array_equal(np.asarray(t1.A), np.asarray(t2.A))
 
 
 def test_trainer_chunked_adaptive_matches_loop_at_boundaries():

@@ -5,7 +5,9 @@
    the sampled scan; backward ops belong to ``fl.local_sgd``;
 2. host spans — the recorder's totals and nesting, the throughput
    meter's block span, the trainer's spans in a CPU profiler trace, the
-   child seconds of the ``timing`` event, and the ``h2d_bytes`` counter.
+   child seconds of the ``timing`` event, the ``h2d_bytes`` and
+   ``prefetched_rounds`` counters, and where the per-round loop stacks
+   the next round's batches.
 """
 
 import re
@@ -213,18 +215,44 @@ def test_timing_event_carries_the_block_child_seconds(k):
     timing = sink.of_kind("timing")
     assert len(timing) == 4 // k == tr.spans.counts[names.BLOCK]
     for i, e in enumerate(timing):
-        stacked = i + 1 < len(timing) or k == 1  # the last chunk prefetches nothing
+        stacked = i + 1 < len(timing)  # the call's last block prefetches nothing
         assert set(e["spans"]) == BLOCK_CHILDREN | ({names.STACK_BATCHES} if stacked else set())
         assert 0 < sum(e["spans"].values()) <= e["seconds"]
     assert [c["seconds"] for c in tr.meter.chunks] == [e["seconds"] for e in timing]
     assert set(tr.spans.seconds) <= set(names.SPANS)
     assert set(tr.spans.counters) <= set(names.COUNTERS)
-    # the chunked run stacks its first block before the first fl.block
+    # the call stacks its first block before the first fl.block
     assert tr.spans.counts[names.STACK_BATCHES] == 4 // k
+    assert tr.spans.counters[names.PREFETCHED_ROUNDS] == 4 - k
     # bytes put on the device: each round's batches, tau_up and tau_dd
     n = tr.rc.n_clients
     per_round = n * T * B * (D * 4 + 4) + 4 * (n + n * n)
     assert tr.spans.counters[names.H2D_BYTES] == 4 * per_round
+
+
+def test_per_round_prefetch_nests_in_the_block_before(monkeypatch):
+    """Round r+1's batches are stacked inside round r's ``fl.block``,
+    after its dispatch and before its fence; the call's first round
+    stacks before its block and its last prefetches nothing."""
+    tr = _trainer(telemetry=False)
+    closed = []
+    close = tr.spans.close
+
+    def spy(span):
+        parent = tr.spans._open[-2].name if len(tr.spans._open) > 1 else None
+        closed.append((span.name, parent))
+        return close(span)
+
+    monkeypatch.setattr(tr.spans, "close", spy)
+    tr.run(3)
+    blk = names.BLOCK
+    steps = [(names.CHANNEL_TRACE, blk), (names.H2D, blk), (names.DISPATCH, blk)]
+    tail = [(names.FENCE, blk), (names.LOG_ROUNDS, blk), (blk, None)]
+    prefetch = [(names.STACK_BATCHES, blk)]
+    assert closed == ([(names.STACK_BATCHES, None)]
+                      + 2 * (steps + prefetch + tail) + steps + tail)
+    assert tr.spans.counters[names.PREFETCHED_ROUNDS] == 2
+    assert names.COUNTERS.count(names.PREFETCHED_ROUNDS) == 1
 
 
 def test_cpu_profiler_trace_holds_the_host_spans(tmp_path):
