@@ -9,5 +9,8 @@ traffic mixes, limits and metrics are found by name:
 - ``chipbench/traffic/<traffic>.json`` holds the federated job;
 - ``chipbench/limits/<cell>.json`` holds the limits of the output check;
 - ``chipbench/metrics/<metric>.py`` reads one metric from a run record;
+- ``chipbench/kinds/<kind>.py`` holds what depends on a model kind: the
+  program's model, initial weights and client data from the seed, and
+  the reference's plain loss;
 - ``chipbench/flops/<kind>.py`` counts a model kind's operations.
 """

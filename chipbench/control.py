@@ -57,7 +57,8 @@ def readings(cell: spec.Cell, seed: int, with_control: bool,
 
     k = int(cell.traffic["chunk"])
     seeds = Seeds.from_seed(seed)
-    job = assemble.build(cell.config, cell.traffic, seeds)
+    kind = cell.kind()
+    job = assemble.build(kind, cell.config, cell.traffic, seeds)
     prog = harness.drive_check_rounds(job, k)
     job.trainer = None
     gc.collect()
@@ -72,14 +73,14 @@ def readings(cell: spec.Cell, seed: int, with_control: bool,
         # a second witness: the same program with every product at
         # float32 (Precision.HIGHEST)
         with jax.default_matmul_precision("highest"):
-            hi = assemble.build(cell.config, cell.traffic, seeds)
+            hi = assemble.build(kind, cell.config, cell.traffic, seeds)
             prog_hi = harness.drive_check_rounds(hi, k)
         hi.trainer = None
         out["highest"] = check.compare(prog_hi, ref)
         out["highest_detail"] = check.detail(prog_hi, ref)
         # a third: the program's aggregation without its kernel
         plain = dict(cell.traffic, strategy_options={"fused": False})
-        faithful = assemble.build(cell.config, plain, seeds)
+        faithful = assemble.build(kind, cell.config, plain, seeds)
         out["faithful"] = check.compare(harness.drive_check_rounds(faithful, k), ref)
         faithful.trainer = None
         tau_up, _ = job.channel_trace(len(ref["losses"]))
@@ -87,7 +88,7 @@ def readings(cell: spec.Cell, seed: int, with_control: bool,
                          "delta_norms": [prog["delta_norms"], ref["delta_norms"]]}
     if not with_control:
         return out
-    low = assemble.build(cell.config, cell.traffic, seeds, dtype="bfloat16")
+    low = assemble.build(kind, cell.config, cell.traffic, seeds, dtype="bfloat16")
     out["control"] = check.compare(harness.drive_check_rounds(low, k), ref)
     low.trainer = None
     gc.collect()

@@ -1,18 +1,17 @@
-"""Inputs made from ``--seed``: per-purpose seeds, synthetic CIFAR-shaped
-images, the partition over clients, and each client's batch indices.
+"""Inputs made from ``--seed`` that do not depend on the model:
+per-purpose seeds, the partition of labelled rows over clients, and each
+client's batch indices.  A model kind makes its rows
+(``chipbench/kinds/<kind>.py``).
 
-The generator follows ``repro.data.synthetic_cifar`` (a smooth random
-template per class plus per-pixel noise) but draws the noise in float32
-from a pool of noise fields, so that set-up stays short; the
-partitioners follow
-``repro.data.partition``.  The benchmark keeps its own copies so that
-the reference never depends on the program's code.
+The partitioners follow ``repro.data.partition``.  The benchmark keeps
+its own copies so that the reference never depends on the program's
+code.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List
+from typing import List
 
 import numpy as np
 
@@ -37,27 +36,6 @@ class Seeds:
         return self.clients + 997 * i
 
 
-def synthetic_images(n: int, seed: int, *, n_classes: int = 10,
-                     image_size: int = 32, channels: int = 3,
-                     noise: float = 0.6, pool: int = 8192):
-    """``(images (n, H, W, C) float32, labels (n,) int32)``: a smooth
-    random template per class plus Gaussian pixel noise.  The noise fields
-    are drawn from a pool of ``pool``, which makes 50,000 images in about
-    a second instead of three."""
-    rng = np.random.default_rng(seed)
-    freq = 4
-    base = rng.normal(size=(n_classes, freq, freq, channels)).astype(np.float32)
-    rep = image_size // freq
-    templates = np.repeat(np.repeat(base, rep, axis=1), rep, axis=2)
-    labels = rng.integers(0, n_classes, size=n).astype(np.int32)
-    fields = rng.standard_normal((min(pool, n), image_size, image_size, channels),
-                                 dtype=np.float32)
-    fields *= np.float32(noise)
-    images = fields[rng.integers(0, len(fields), size=n)]
-    images += templates[labels]
-    return images, labels
-
-
 def partition(labels: np.ndarray, n_clients: int, spec: dict,
               seed: int) -> List[np.ndarray]:
     """Sample indices per client: ``{"kind": "iid"}`` or
@@ -75,10 +53,6 @@ def partition(labels: np.ndarray, n_clients: int, spec: dict,
         return [np.sort(np.concatenate([shards[t] for t in ids[c * s:(c + 1) * s]]))
                 for c in range(n_clients)]
     raise ValueError(f"unknown partition kind {spec['kind']!r}")
-
-
-def client_arrays(images, labels, parts) -> List[Dict[str, np.ndarray]]:
-    return [{"images": images[idx], "labels": labels[idx]} for idx in parts]
 
 
 def batch_indices(seed: int, n_rows: int, steps: int, batch: int) -> np.ndarray:

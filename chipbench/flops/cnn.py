@@ -8,7 +8,7 @@ gradient with respect to activations, and with respect to weights).
 
 from __future__ import annotations
 
-from chipbench.weights import cnn_blocks
+from chipbench.kinds.cnn import cnn_blocks
 
 
 def forward_macs(model: dict) -> int:

@@ -6,10 +6,26 @@ the window's own call (``FLTrainer.run(K, chunk=K)``, block by block)
 and keeps what the check compares, then runs two more blocks to time
 one.  The window is one ``run(R, chunk=K)`` call, R a multiple of K set
 from that time, fenced at its end; no program compiles inside it (the
-count of compilations in the window is printed).  With ``--trace 1`` a
-short traced stretch follows the window.  Once the window has closed
-and peak memory has been read, the program's state is dropped and the
-reference follows the same first rounds.
+count of compilations in the window is printed).  The trainer's host-span
+totals are taken before and after the window.  With ``--trace 1`` the
+executed program's instructions are mapped to device scopes and a short
+traced stretch follows the window.  Once the window has closed and peak
+memory has been read, the program's state is dropped and the reference
+follows the same first rounds.
+
+The metric readers (``chipbench/metrics/<name>.py``) get the run's
+record:
+
+- ``setup_s``, ``build_s``, ``warmup_s``: seconds of set-up;
+- ``window``: ``rounds`` and ``seconds`` of the window, and ``spans``
+  (:func:`window_split`: each host span's milliseconds, each span's
+  count and each counter, per round of the window);
+- ``trace``: ``None`` untraced; else :func:`chipbench.scopes.reduce` of
+  the traced stretch (seconds over its ``traced_rounds`` rounds:
+  ``busy_s``, ``window_s``, ``ops``, ``scopes`` by device scope,
+  ``idle_by_span`` by host span, ...) and its ``rounds_per_s``;
+- ``traced_rounds``, ``op_scopes_s``, ``peaks``, ``chips``,
+  ``round_flops``, ``n_clients``, ``d``, ``memory_peak_bytes``.
 """
 
 from __future__ import annotations
@@ -100,13 +116,25 @@ def profiler_options():
     return options
 
 
-def _traced_stretch(trainer, rounds: int, k: int,
+def window_split(before: dict, after: dict, rounds: int) -> dict:
+    """Milliseconds per round of each host span, and counts and counters
+    per round, between two ``Spans.snapshot()`` s."""
+    def per_round(key, scale=1.0):
+        return {k: (v - before[key].get(k, 0)) * scale / rounds
+                for k, v in after[key].items() if v != before[key].get(k, 0)}
+
+    return {"span_ms": per_round("seconds", 1e3), "counts": per_round("counts"),
+            "counters": per_round("counters")}
+
+
+def _traced_stretch(trainer, rounds: int, k: int, op_scopes: dict,
                     keep: Optional[pathlib.Path] = None) -> dict:
-    """Trace ``rounds`` rounds of the window's call and reduce the trace;
+    """Trace ``rounds`` rounds of the window's call and reduce the trace
+    by the program's names (``op_scopes``: ``FLTrainer.op_scopes(k)``);
     ``keep`` names a file to hold the trace, gzipped."""
     import jax
 
-    from chipbench import trace
+    from chipbench import scopes, trace
 
     tmp = tempfile.mkdtemp(prefix="chipbench_trace_")
     try:
@@ -122,7 +150,7 @@ def _traced_stretch(trainer, rounds: int, k: int,
         path = glob.glob(os.path.join(tmp, "**", "*.xplane.pb"), recursive=True)
         if keep is not None:
             keep.write_bytes(gzip.compress(pathlib.Path(path[0]).read_bytes()))
-        return dict(trace.reduce_trace(trace.load(path[0])),
+        return dict(scopes.reduce(trace.load(path[0]), op_scopes),
                     rounds_per_s=rounds / seconds)
     finally:
         shutil.rmtree(tmp, ignore_errors=True)
@@ -181,9 +209,9 @@ def reference_of(job, k: int, fault: Optional[str] = None, A=None) -> dict:
     links = alpha.link_model(job.traffic["links"])
     if A is None:
         A = alpha.copt_alpha_job(*links, int(job.traffic["copt_sweeps"]))
-    ref = reference.run_rounds(job.model, job.traffic, job.params0, job.clients,
-                               job.batch_indices(rounds), tau_up, tau_dd, A,
-                               rounds, k, fault=fault)
+    ref = reference.run_rounds(job.kind, job.model, job.traffic, job.params0,
+                               job.clients, job.batch_indices(rounds), tau_up,
+                               tau_dd, A, rounds, k, fault=fault)
     ref.update(params0=job.params0, A=A, A_settled=alpha.copt_alpha(*links),
                links=links)
     return ref
@@ -204,7 +232,8 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     jax.monitoring.register_event_duration_secs_listener(compiles)
 
     t = time.perf_counter()
-    job = assemble.build(cell.config, cell.traffic, Seeds.from_seed(seed))
+    job = assemble.build(cell.kind(), cell.config, cell.traffic,
+                         Seeds.from_seed(seed))
     build_s = time.perf_counter() - t
 
     t = time.perf_counter()
@@ -214,25 +243,29 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     warmup_s = time.perf_counter() - t
 
     setup_s = time.perf_counter() - t_start
-    before = compiles.count
+    before, spans = compiles.count, tr.spans.snapshot()
     t = time.perf_counter()
     tr.run(rounds, chunk=k)
     jax.block_until_ready(tr.params)
     window_s = time.perf_counter() - t
     window_compiles = compiles.count - before
+    spans = window_split(spans, tr.spans.snapshot(), rounds)
     losses = np.asarray(tr.log.loss[-rounds:], np.float64)
 
     record = {"setup_s": setup_s, "build_s": build_s, "warmup_s": warmup_s,
-              "window": {"rounds": rounds, "seconds": window_s},
+              "window": {"rounds": rounds, "seconds": window_s, "spans": spans},
               "peaks": peaks, "chips": cell.chips,
               "round_flops": cell.flops().round_flops(cell.config["model"],
                                                        cell.traffic),
               "n_clients": int(cell.traffic["n_clients"]), "d": job.d,
               "trace": None, "traced_rounds": 0}
     if traced:
+        t = time.perf_counter()
+        op_scopes = tr.op_scopes(k)
+        record["op_scopes_s"] = time.perf_counter() - t
         per_round = window_s / rounds
         record["traced_rounds"] = k * max(3, math.ceil(TRACE_SECONDS / per_round / k))
-        record["trace"] = _traced_stretch(tr, record["traced_rounds"], k)
+        record["trace"] = _traced_stretch(tr, record["traced_rounds"], k, op_scopes)
     record["memory_peak_bytes"] = _peak_bytes(devices)
 
     job.trainer = tr = None
@@ -256,11 +289,24 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
               "window_compiles": window_compiles}
     if traced:
         result["traced_rounds_per_s"] = record["trace"]["rounds_per_s"]
+        result["op_scopes_s"] = record["op_scopes_s"]
+        result["layers"] = _layers(record)
         result["breakdown"] = {
             "device_ops": [[n, s] for n, s in record["trace"]["top_ops"]],
             "idle_gaps": [[n, s] for n, s in record["trace"]["idle_by_label"]]}
     result["checks"] = check.report(numbers, limits)
     return {"result": result, "check_lines": check.lines(numbers, limits)}
+
+
+def _layers(record: dict) -> dict:
+    """The traced run's split by the program's names: device milliseconds
+    by scope and idle milliseconds by host span, per traced round; host
+    milliseconds by span, span counts and counters, per window round."""
+    trace, rounds = record["trace"], record["traced_rounds"]
+    return {"scope_ms": {s: v * 1e3 / rounds for s, v in trace["scopes"].items()},
+            "idle_by_span_ms": {s: v * 1e3 / rounds
+                                for s, v in trace["idle_by_span"].items()},
+            **record["window"]["spans"]}
 
 
 def main(argv=None, *, root: Optional[pathlib.Path] = None,

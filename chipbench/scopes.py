@@ -22,7 +22,7 @@ A trace from a program without these names reduces to ``unscoped`` and
 from __future__ import annotations
 
 import collections
-from typing import Dict, List, Mapping, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -111,3 +111,16 @@ def reduce(pd, op_scopes: Mapping[str, str]) -> dict:
     out["scopes"] = scope_seconds(out["ops"], op_scopes)
     out["idle_by_span"] = idle_by_span(pd)
     return out
+
+
+def scope_ms(record: dict, names: Sequence[str]) -> Optional[float]:
+    """Device milliseconds per traced round under the scopes ``names``
+    together, from a run record's reduced trace; ``None`` untraced, or
+    where the trace holds none of them."""
+    trace = record.get("trace")
+    if not trace or not record.get("traced_rounds"):
+        return None
+    found = [trace["scopes"][s] for s in names if s in trace["scopes"]]
+    if not found:
+        return None
+    return 1e3 * sum(found) / record["traced_rounds"]

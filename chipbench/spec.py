@@ -1,5 +1,6 @@
 """Finding a cell's files by name: configuration, traffic, limits, metric
-readers and FLOP counters.  Nothing here imports JAX or the program."""
+readers, and the model kind's module and FLOP counter.  Nothing here
+imports JAX or the program."""
 
 from __future__ import annotations
 
@@ -7,6 +8,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import sys
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parent
@@ -18,11 +20,22 @@ def load_json(path: pathlib.Path) -> Any:
 
 
 def _load_module(path: pathlib.Path, name: str):
+    """The module in ``path``, loaded once a process under ``name`` (a
+    file of that name elsewhere, as in another checkout, is loaded
+    anew)."""
     if not path.is_file():
         raise FileNotFoundError(f"no file {path}")
+    mod = sys.modules.get(name)
+    if mod is not None and pathlib.Path(mod.__file__) == path:
+        return mod
     spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[name] = mod  # before it runs, as an import does (dataclasses look there)
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
     return mod
 
 
@@ -47,6 +60,15 @@ class Cell:
         mod = _load_module(self.bench_dir / "metrics" / f"{metric}.py",
                            f"chipbench_metric_{metric}")
         return mod.read
+
+    def kind(self):
+        """``chipbench/kinds/<kind>.py`` for the configuration's kind:
+        ``program_model(model, dtype)``, ``init_params(model, seed,
+        dtype)``, ``client_arrays(model, traffic, seeds)`` and the
+        reference's ``loss(model, params, batch)``."""
+        kind = self.config["kind"]
+        return _load_module(self.bench_dir / "kinds" / f"{kind}.py",
+                            f"chipbench_kind_{kind}")
 
     def flops(self):
         """``chipbench/flops/<kind>.py`` for the configuration's kind."""

@@ -1,4 +1,4 @@
-"""A tiny cell in a temporary copy of the benchmark, for the CPU tests.
+"""Tiny cells in a temporary copy of the benchmark, for the CPU tests.
 
 ``tiny_root(tmp)`` copies ``chipbench/`` and ``BENCHMARK.json`` into
 ``tmp`` and adds, as new files only, a thin ResNet configuration
@@ -7,6 +7,12 @@ of the paper's job cut to 2 local steps of batch 4 over 400 images in
 chunks of 2, with COPT-alpha run until it settles (so that the job's relay
 weights are also the optimum ``alpha_excess`` measures against), and the limits of
 ``resnet20.paper_chunk8`` for its cell ``tiny.chunk2``.
+
+``toy_root(tmp)`` copies the same and adds, as new files only, a model
+kind the benchmark has never seen: ``tests/chipbench/toy/`` (a kind
+module, its FLOP counter, a configuration, a traffic mix, limits, and a
+metric that reads a span by name), entered in ``BENCHMARK.json`` as the
+cell ``toy.chunk2``.
 """
 
 from __future__ import annotations
@@ -47,6 +53,32 @@ def tiny_root(tmp: pathlib.Path, chunk: int = 2) -> pathlib.Path:
     bench["workloads"].append({"name": CELL, "config": "tiny",
                                "traffic": "tiny_chunk2", "chips": 1,
                                "why": "test"})
+    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
+    return tmp
+
+
+TOY = REPO / "tests/chipbench/toy"
+TOY_CELL = "toy.chunk2"
+TOY_METRIC = "dispatch_ms"
+
+
+def toy_root(tmp: pathlib.Path) -> pathlib.Path:
+    tmp = pathlib.Path(tmp)
+    shutil.copytree(REPO / "chipbench", tmp / "chipbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copytree(TOY, tmp / "chipbench", dirs_exist_ok=True,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = json.loads((REPO / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "toy", "source": "test",
+                             "file": "chipbench/configs/toy.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": TOY_CELL, "config": "toy",
+                               "traffic": "toy_chunk2", "chips": 1,
+                               "why": "test"})
+    bench["per_layer"].append({
+        "name": TOY_METRIC, "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "host loop", "moves": "rounds_per_s",
+        "workloads": [TOY_CELL]})
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp
 

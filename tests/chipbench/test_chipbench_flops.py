@@ -65,12 +65,11 @@ def test_param_count_matches_hand_count_and_program(name):
     import jax
     import numpy as np
 
-    from chipbench.assemble import cnn_config
-    from repro.models import build
+    from chipbench.kinds import cnn as cnn_kind
 
     model = _model(name)
     assert cnn.param_count(model) == HAND[name][1]
-    shapes = jax.eval_shape(build(cnn_config(model)).init, jax.random.PRNGKey(0))
+    shapes = jax.eval_shape(cnn_kind.program_model(model).init, jax.random.PRNGKey(0))
     assert sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes)) == HAND[name][1]
 
 

@@ -7,6 +7,7 @@ import pytest
 from chipbench_tiny import CELL, THIN, tiny_root
 
 from chipbench import alpha, assemble, check, control, data, spec
+from chipbench.kinds import cnn
 
 SEED = 7
 TRAFFIC = {"n_clients": 10, "local_steps": 2, "batch_size": 4, "data_size": 400,
@@ -22,7 +23,6 @@ def test_assembled_first_round_equals_build_experiment_bitwise():
 
     from repro.data import partition_sort_and_partition, synthetic_cifar
     from repro.fl import ExperimentSpec, build_experiment
-    from repro.models import build
 
     exp = build_experiment(ExperimentSpec(
         model="cifar_cnn", topology="fig2b", non_iid_s=3, strategy="colrel",
@@ -34,10 +34,12 @@ def test_assembled_first_round_equals_build_experiment_bitwise():
         np.concatenate(partition_sort_and_partition(labels, 10, s=3, seed=SEED)))
     seeds = data.Seeds(data=SEED + 1, partition=SEED, init=SEED, channel=SEED,
                        clients=SEED)
-    job = assemble.build({"model": THIN}, TRAFFIC, seeds,
-                         init_params=build(assemble.cnn_config(THIN)).init(
+    clients = [{"images": images[idx], "labels": labels[idx]}
+               for idx in data.partition(labels, 10, TRAFFIC["partition"], SEED)]
+    job = assemble.build(cnn, {"model": THIN}, TRAFFIC, seeds,
+                         init_params=cnn.program_model(THIN).init(
                              jax.random.PRNGKey(SEED)),
-                         images=images, labels=labels)
+                         clients=clients)
     np.testing.assert_array_equal(job.A, exp.A)
     exp.run(1, chunk=1)
     job.trainer.run(1, chunk=1)

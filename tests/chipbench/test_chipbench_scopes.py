@@ -1,9 +1,9 @@
-"""The reduction of a trace by the program's names (``chipbench/scopes.py``)
-and the split that ``chipbench/layer_split.py`` prints, on synthetic
-intervals and records, on the trace recorded before the program named
-its work (``trace_small``), and on one recorded after
-(``chipbench/testdata/trace_scoped.xplane.pb.gz`` with its scope map:
-the thin job of ``trace_small``, recorded on one TPU v5e by
+"""The reduction of a trace by the program's names (``chipbench/scopes.py``),
+the harness's split of a window by host span, and the per-layer metrics
+read from both, on synthetic intervals and records, on the trace
+recorded before the program named its work (``trace_small``), and on one
+recorded after (``chipbench/testdata/trace_scoped.xplane.pb.gz`` with its
+scope map: the thin job of ``trace_small``, recorded on one TPU v5e by
 ``python3 chipbench/record_scoped_trace.py``)."""
 
 import json
@@ -12,11 +12,20 @@ import pytest
 
 from chipbench_tiny import REPO
 
-from chipbench import layer_split, scopes, trace
+from chipbench import harness, scopes, spec, trace
 from chipbench.record_scoped_trace import scope_map_path
 
 SMALL = REPO / "chipbench/testdata/trace_small.xplane.pb.gz"
 SCOPED = REPO / "chipbench/testdata/trace_scoped.xplane.pb.gz"
+SCOPE_METRICS = ("local_train_ms", "aggregate_ms", "server_step_ms")
+SPAN_METRICS = ("host_stack_ms", "h2d_ms")
+
+
+def _readers():
+    cell = spec.load_cell(REPO, "resnet20.paper_chunk8")
+    names = [m["name"] for m in cell.per_layer]
+    assert set(SCOPE_METRICS + SPAN_METRICS) <= set(names)
+    return {m: cell.reader(m) for m in SCOPE_METRICS + SPAN_METRICS}
 
 
 def test_pieces_name_each_stretch_by_its_innermost_span():
@@ -88,18 +97,39 @@ def test_layer_split_numbers_from_a_window_and_a_trace():
               "counts": {"fl.block": 2}, "counters": {"h2d_bytes": 100}}
     after = {"seconds": {"fl.block": 3.0, "fl.h2d": 0.9, "fl.stack_batches": 0.2},
              "counts": {"fl.block": 6}, "counters": {"h2d_bytes": 500}}
-    split = layer_split.window_split(before, after, rounds=4)
+    split = harness.window_split(before, after, rounds=4)
     assert split["span_ms"] == pytest.approx(
         {"fl.block": 500.0, "fl.h2d": 100.0, "fl.stack_batches": 50.0})
     assert split["counts"] == {"fl.block": 1.0}
     assert split["counters"] == {"h2d_bytes": 100.0}
     traced = {"scopes": {"fl.local_sgd": 0.8, "fl.aggregate": 0.02, "fl.flatten": 0.02,
                          "fl.server_step": 0.004, scopes.UNSCOPED: 0.1}}
-    got = layer_split.per_layer(split, traced, traced_rounds=8)
+    record = {"window": {"spans": split}, "trace": traced, "traced_rounds": 8}
+    got = {m: read(record) for m, read in _readers().items()}
     assert got == pytest.approx({"local_train_ms": 100.0, "aggregate_ms": 5.0,
                                  "server_step_ms": 0.5, "host_stack_ms": 50.0,
                                  "h2d_ms": 100.0})
-    # a program without the names gives zeros, and raises nothing
-    empty = layer_split.per_layer(layer_split.window_split(before, before, 4),
-                                  {"scopes": {scopes.UNSCOPED: 1.0}}, 8)
-    assert set(empty.values()) == {0.0}
+    # a program without the names gives nothing to read, and raises nothing
+    empty = {"window": {"spans": harness.window_split(before, before, 4)},
+             "trace": {"scopes": {scopes.UNSCOPED: 1.0}}, "traced_rounds": 8}
+    assert {m: read(empty) for m, read in _readers().items()} == dict.fromkeys(
+        SCOPE_METRICS + SPAN_METRICS)
+    # nor does an untraced run, for the device's three
+    untraced = dict(record, trace=None, traced_rounds=0)
+    assert [_readers()[m](untraced) for m in SCOPE_METRICS] == [None] * 3
+
+
+def test_scope_readers_on_the_recorded_trace(scoped):
+    reduced, _ = scoped
+    record = {"trace": reduced, "traced_rounds": 4}
+    readers = _readers()
+    got = {m: readers[m](record) for m in SCOPE_METRICS}
+    assert all(v > 0 for v in got.values()), got
+    # the three scopes lie inside the busy time of a traced round
+    busy_ms = reduced["busy_s"] * 1e3 / 4
+    assert sum(got.values()) <= busy_ms
+    assert got["local_train_ms"] == pytest.approx(
+        reduced["scopes"]["fl.local_sgd"] * 1e3 / 4)
+    assert got["aggregate_ms"] == pytest.approx(
+        (reduced["scopes"]["fl.aggregate"] + reduced["scopes"].get("fl.flatten", 0)) * 1e3 / 4)
+    assert max(got, key=got.get) == "local_train_ms"

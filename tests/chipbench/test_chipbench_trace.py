@@ -2,7 +2,9 @@
 one TPU v5e (``chipbench/testdata/trace_small.xplane.pb.gz``: four
 rounds of the thin ResNet in chunks of 2, one local step of batch 4,
 n = 10, d = 19,858, with the fused aggregation kernel; recorded through
-the harness's traced stretch by ``python3 chipbench/record_trace.py``)."""
+the harness's traced stretch, the job of
+``chipbench/record_scoped_trace.py`` before the program named its
+work)."""
 
 import pytest
 
