@@ -22,6 +22,8 @@ from .relay_block import (
     block_row_stream_pallas,
 )
 from .relay_mix import relay_mix_pallas
+from .ssd_intra import ssd_intra_pallas
+from .ssd_intra import tiles as ssd_intra_tiles
 from .ssd_scan import ssd_scan_pallas
 
 
@@ -234,3 +236,27 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = 
 def ssd_scan(q, k, v, log_decay, *, chunk: int = 64):
     """Chunked SSD recurrence (Mamba2 hot loop), (BH, T, D) layout."""
     return ssd_scan_pallas(q, k, v, log_decay, chunk=chunk, interpret=_interpret())
+
+
+def ssd_intra(C: jax.Array, B: jax.Array, x: jax.Array, c: jax.Array, *,
+              chunk: int) -> jax.Array:
+    """Intra-chunk term of the one-group SSD, float32 (Bt, T, H, P):
+    ``y_t = sum_{s<=t, same chunk} (C_t . B_s) exp(c_t - c_s) x_s`` per
+    head, for C, B (Bt, T, N) shared by every head, x (Bt, T, H, P) and
+    c (Bt, T, H) the inclusive cumulative log decays restarted at each
+    chunk.  On a TPU, whenever the blocks tile (``ssd_intra.tiles``: the
+    chunk a multiple of 128 or the whole sequence, the head size and the
+    head block a multiple of 8), the fused kernel keeps every (Q, Q) matrix in VMEM,
+    forward and backward."""
+    if _interpret() or not ssd_intra_tiles(x.shape[1], x.shape[2], x.shape[3], chunk):
+        # Non-TPU deployable op, and the fallback for blocks that do not
+        # tile: the same shared-group algebra in jnp, with C B^T taken once
+        # per chunk for every head.
+        bt, t, h, p = x.shape
+        nc = t // chunk
+        scores = jnp.einsum("bnqd,bnsd->bnqs", C.reshape(bt, nc, chunk, -1),
+                            B.reshape(bt, nc, chunk, -1))
+        ch = c.reshape(bt, nc, chunk, h).transpose(0, 1, 3, 2)
+        y = ref.ssd_intra_ref(scores[:, :, None], ch, x.reshape(bt, nc, chunk, h, p))
+        return y.reshape(bt, t, h, p)
+    return ssd_intra_pallas(C, B, x, c, chunk)

@@ -50,3 +50,15 @@ def ssd_scan_ref(q, k, v, log_decay):
         S = a[:, t, None, None] * S + np.einsum("bk,bv->bkv", kf[:, t], vf[:, t])
         out[:, t] = np.einsum("bk,bkv->bv", qf[:, t], S)
     return jnp.asarray(out, q.dtype)
+
+
+def ssd_intra_ref(scores, c, v):
+    """The SSD's intra-chunk term, ``y_t = sum_{s<=t} scores[t, s]
+    exp(c_t - c_s) v_s`` within each chunk: scores (B, nc, H or 1, Q, Q),
+    c (B, nc, H, Q) inclusive cumulative log decays, v (B, nc, Q, H, P).
+    Every exponent is non-positive and the mask is exp(-inf) = 0."""
+    q = c.shape[-1]
+    tri = jnp.tril(jnp.ones((q, q), bool))
+    decay = c[..., :, None] - c[..., None, :]  # [t, s] = c_t - c_s
+    w = jnp.exp(jnp.where(tri, jnp.minimum(decay, 0.0), -jnp.inf))
+    return jnp.einsum("bnhqs,bnshd->bnqhd", scores * w, v)

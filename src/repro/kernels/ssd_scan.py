@@ -13,8 +13,11 @@ Per chunk (Q tokens):
 All decay exponents are differences of cumulative log-decays and are
 <= 0 by construction — no overflow, no rescaling passes.
 
-The jnp twin is ``repro.models.ssm.ssd_chunked`` (used by jamba); the
-oracle for tests is ``ssm.ssd_reference``.
+Its jnp counterpart is ``repro.models.ssm.ssd_chunked`` (per-head q and
+k); the oracle for tests is ``ssm.ssd_reference``.  The model's Mamba-2
+mixers run ``ssm.ssd_one_group`` instead, whose intra-chunk term is the
+fused kernel of ``ssd_intra.py`` (forward and backward); this kernel has
+no backward and stays a forward-only kernel.
 """
 
 from __future__ import annotations

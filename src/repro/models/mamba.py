@@ -1,9 +1,10 @@
 """Mamba block (Mamba2/SSD-style, the TPU-native selective SSM).
 
-Used by the jamba hybrid config.  Per-layer parameters follow the Mamba2
+Used by the jamba and granite hybrid configs.  Per-layer parameters follow the Mamba2
 structure: fused in-projection -> (gate z, conv channels xBC, dt), causal
 depthwise conv, scalar-per-head decay ``a_t = exp(-exp(A_log) * dt_t)``,
-chunked SSD mixer (see ``ssm.py``), gated RMS norm, out-projection.
+chunked SSD mixer with one group (``ssm.ssd_one_group``: B and C shared by
+every head, never broadcast to them), gated RMS norm, out-projection.
 
 Decode carries two states per layer: the conv window (last ``d_conv - 1``
 inputs) and the SSD state (B, H, d_state, head_dim).
@@ -96,15 +97,13 @@ def _mamba_forward(cfg, p, x, state):
     xBC = jax.nn.silu(_causal_conv(xBC_pre, p["conv_w"], p["conv_b"]))
     xs, Bc, Cc = jnp.split(xBC, [d_in, d_in + N], axis=-1)
     v = xs.reshape(B, T, H, P)
-    k = jnp.broadcast_to(Bc[:, :, None, :], (B, T, H, N))
-    q = jnp.broadcast_to(Cc[:, :, None, :], (B, T, H, N))
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])  # (B,T,H)
     loga = -jnp.exp(p["A_log"]) * dt
     v_in = v * dt[..., None].astype(v.dtype)
     ssm_state = None if state is None else state["ssm"]
     with jax.named_scope(names.MODEL_SSD):
-        y, ssm_out = ssm.ssd_chunked(
-            q, k, v_in, loga, state=ssm_state, chunk=min(cfg.ssm_chunk, T)
+        y, ssm_out = ssm.ssd_one_group(
+            Cc, Bc, v_in, loga, state=ssm_state, chunk=min(cfg.ssm_chunk, T)
         )
     y = y + v * p["D"][None, None, :, None].astype(v.dtype)
     y = y.reshape(B, T, d_in)

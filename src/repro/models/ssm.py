@@ -24,6 +24,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops, ref
+
 Array = jax.Array
 
 
@@ -55,11 +57,7 @@ def ssd_chunked(
 
     # intra-chunk: y_t += sum_{s<=t} exp(c_t - c_s) (q_t . k_s) v_s
     scores = jnp.einsum("bnqhd,bnshd->bnhqs", qc, kc)
-    decay = c[..., :, None, :].transpose(0, 1, 4, 2, 3) - c[..., None, :, :].transpose(0, 1, 4, 2, 3)
-    # decay[b,n,h,t,s] = c_t - c_s
-    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
-    w = jnp.exp(jnp.where(tri, jnp.minimum(decay, 0.0), -jnp.inf))
-    y_intra = jnp.einsum("bnhqs,bnshd->bnqhd", scores * w, vc)
+    y_intra = ref.ssd_intra_ref(scores, c.transpose(0, 1, 3, 2), vc)
 
     # chunk summaries
     clast = c[:, :, -1, :]  # (B, N, H)
@@ -89,6 +87,53 @@ def ssd_chunked(
 
     y = (y_intra + y_inter).reshape(B, T, H, Dv).astype(q.dtype)
     return y, state
+
+
+def ssd_one_group(
+    C: Array, B: Array, x: Array, loga: Array, state: Optional[Array] = None, chunk: int = 64
+) -> Tuple[Array, Array]:
+    """``ssd_chunked`` for one group: C (the queries) and B (the keys),
+    (B, T, N), are shared by every head and never broadcast to them.
+    x: (B, T, H, P); loga: (B, T, H).  Returns (y (B, T, H, P), final
+    state (B, H, N, P)).
+
+    The intra-chunk term is ``ops.ssd_intra`` (a fused kernel on a TPU).
+    Between chunks the decay is applied on the x side:
+    ``chunk_state_h = B^T (exp(c_last - c)_h ⊙ x_h)`` and
+    ``y_inter_h = exp(c)_h ⊙ (C S_h)``."""
+    Bt, T, H, P = x.shape
+    f32 = jnp.float32
+    c = jnp.cumsum(_chunk(loga, chunk).astype(f32), axis=2)  # (B, nc, Q, H)
+    y_intra = ops.ssd_intra(
+        C.astype(f32), B.astype(f32), x.astype(f32), c.reshape(Bt, T, H), chunk=chunk
+    )
+    Cc = _chunk(C, chunk).astype(f32)  # (B, nc, Q, N)
+    Bc = _chunk(B, chunk).astype(f32)
+    xc = _chunk(x, chunk).astype(f32)  # (B, nc, Q, H, P)
+    clast = c[:, :, -1, :]  # (B, nc, H)
+    # states are carried transposed, (B, H, P, N): N is the minor, lane-dense
+    # axis, and each product below is one matmul per chunk, (H P, Q) x (Q, N)
+    # or (Q, N) x (N, H P)
+    xdec = xc * jnp.exp(clast[:, :, None, :] - c)[..., None]
+    chunk_states = jnp.einsum("bnsd,bnshp->nbhpd", Bc, xdec)  # (nc, B, H, P, N)
+
+    if state is None:
+        state = jnp.zeros((Bt, H, C.shape[-1], P), f32)
+
+    def step(S, inp):
+        cs, cl = inp
+        return jnp.exp(cl)[..., None, None] * S + cs, S
+
+    # scan over chunks (leading axis nc); S_in is the state entering each
+    S, S_in = jax.lax.scan(
+        step, state.astype(f32).transpose(0, 1, 3, 2), (chunk_states, clast.transpose(1, 0, 2))
+    )
+    # token-minor (B, nc, H, P, Q), as the intra-chunk term leaves the kernel
+    y_inter = jnp.einsum("nbhpd,bnqd->bnhpq", S_in, Cc)
+    y_inter = y_inter * jnp.exp(c).transpose(0, 1, 3, 2)[..., None, :]
+    y = y_intra + y_inter.transpose(0, 1, 4, 2, 3).reshape(Bt, T, H, P)
+    state = S.transpose(0, 1, 3, 2)
+    return y.astype(x.dtype), state
 
 
 def ssd_step(q: Array, k: Array, v: Array, loga: Array, state: Array) -> Tuple[Array, Array]:

@@ -147,3 +147,32 @@ def test_per_client_kernel_round_compiles_for_v5e(one_chip, on_tpu):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < HBM_BYTES
+
+
+def test_ssd_intra_kernels_keep_their_names_under_model_ssd_for_v5e(one_chip, on_tpu):
+    """The one-group SSD at granite-4.0-h-micro's widths (64 heads of 64,
+    state 128, chunk 256; 10 rows of 512 tokens), rematerialized as the
+    hybrid's sub-layers are: the fused intra-chunk kernel compiles
+    forward and backward, and the benchmark's ``ssd_ms`` finds both
+    custom calls under ``model.ssd``."""
+    from repro.models import ssm
+    from repro.telemetry import op_scopes
+    from repro.telemetry import spans as names
+
+    B, T, H, P, N, Q = 10, 512, 64, 64, 128, 256
+
+    @jax.checkpoint
+    def mixer(C, Bm, x, loga):
+        with jax.named_scope(names.MODEL_SSD):
+            return ssm.ssd_one_group(C, Bm, x, loga, chunk=Q)[0]
+
+    def loss(*a):
+        return jnp.sum(mixer(*a) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        _spec(one_chip, (B, T, N)), _spec(one_chip, (B, T, N)),
+        _spec(one_chip, (B, T, H, P)), _spec(one_chip, (B, T, H))).compile().as_text()
+    found = {(op.split(".")[0], scope) for op, scope in op_scopes(text).items()
+             if op.startswith("ssd_intra_pallas")}
+    assert found == {("ssd_intra_pallas", names.MODEL_SSD),
+                     ("ssd_intra_pallas_bwd", names.MODEL_SSD)}
